@@ -11,6 +11,8 @@ import argparse
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (bench_ablation_objective, bench_batch_dist, bench_batch_eval,
                bench_cardinality, bench_convergence, bench_cost_savings,
                bench_exploration_cost, bench_load_change, bench_pool_example,
@@ -42,6 +44,7 @@ def main():
     ap.add_argument("--only", default="")
     args = ap.parse_args()
     only = {s.strip() for s in args.only.split(",") if s.strip()}
+    enable_compile_cache()
 
     failures, summary = [], []
     for name, mod in BENCHES:

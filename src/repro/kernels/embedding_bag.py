@@ -4,8 +4,14 @@ Recsys models (MT-WND / DIEN) and LM token embeddings are gather-bound: rows
 scattered across a huge HBM-resident table.  TPU-native design: the bag
 indices are *scalar-prefetched* so they are available to the BlockSpec
 index_map BEFORE the DMA engine issues the row fetch — each (bag, slot) grid
-step DMAs exactly the (1, D) row it needs HBM→VMEM, and the bag's running sum
+step DMAs exactly the one row it needs HBM→VMEM, and the bag's running sum
 accumulates in the output block (revisited across the inner grid axis).
+
+Layout: the table is viewed as (V, 1, D) and the output as (n_bags, 1, D),
+and both are blocked as (None, 1, D).  A plain (1, D) block of a (V, D)
+array breaks the TPU's (8, 128) tiling rule on its second-to-last axis; in
+the 3-D view that axis is the whole (size-1) dimension, which Mosaic
+accepts, and the row index moves to a squeezed leading axis.
 
 This is the TPU analogue of FBGEMM's TBE gather-reduce: no atomics, one
 row-granular DMA per lookup, MXU untouched (pure VPU adds).
@@ -29,11 +35,11 @@ def _bag_kernel(idx_ref, w_ref, table_ref, o_ref, *, bag_size: int,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    row = table_ref[0]                          # (D,)
+    row = table_ref[...]                        # (1, D)
     if weighted:
         i = pl.program_id(0)
-        row = row * w_ref[i, j]
-    o_ref[0] += row.astype(o_ref.dtype)
+        row = row.astype(jnp.float32) * w_ref[i, j]
+    o_ref[...] += row.astype(o_ref.dtype)
 
 
 def embedding_bag(indices, table, weights=None, *, interpret: bool = False):
@@ -41,13 +47,14 @@ def embedding_bag(indices, table, weights=None, *, interpret: bool = False):
 
     weights (n_bags, bag_size) optional per-lookup multipliers (e.g. recsys
     multi-hot frequencies).  Rows are fetched via scalar-prefetch-driven
-    index maps.
+    index maps; the weights sit in SMEM as float32 scalars, because a
+    dynamically indexed scalar load from VMEM does not lower.
     """
     n_bags, bag_size = indices.shape
     v, d = table.shape
     weighted = weights is not None
     if weights is None:
-        weights = jnp.ones((n_bags, bag_size), table.dtype)
+        weights = jnp.ones((n_bags, bag_size), jnp.float32)
 
     kernel = functools.partial(_bag_kernel, bag_size=bag_size,
                                weighted=weighted)
@@ -55,14 +62,15 @@ def embedding_bag(indices, table, weights=None, *, interpret: bool = False):
         num_scalar_prefetch=1,
         grid=(n_bags, bag_size),
         in_specs=[
-            pl.BlockSpec((n_bags, bag_size), lambda i, j, idx: (0, 0)),
-            pl.BlockSpec((1, d), lambda i, j, idx: (idx[i, j], 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, d), lambda i, j, idx: (idx[i, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, j, idx: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, d), lambda i, j, idx: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_bags, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_bags, 1, d), table.dtype),
         interpret=interpret,
-    )(indices, weights, table)
+    )(indices, weights.astype(jnp.float32), table.reshape(v, 1, d))
+    return out.reshape(n_bags, d)

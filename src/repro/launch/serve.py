@@ -10,18 +10,21 @@ after cell loss.
 from __future__ import annotations
 
 import argparse
-
+from dataclasses import replace
 
 from ..core import RibbonOptimizer, SearchSpace
 from ..serving.engine import DEFAULT_TPU_CELLS, ClusterEngine
 from ..serving.workload import WorkloadSpec
+from .compile_cache import enable_compile_cache
 
 
 def serve(model: str = "mtwnd", n_queries: int = 60, rate_qps: float = 40.0,
           qos_latency: float = 0.2, qos_target: float = 0.9,
           bounds=(4, 3, 2), budget: int = 12, seed: int = 0,
-          verbose: bool = True):
-    cells = DEFAULT_TPU_CELLS
+          preset: str = "full", verbose: bool = True):
+    """``preset`` sizes the model every cell serves: ``"full"`` is the
+    published width (paper_models.*_PRESETS), ``"smoke"`` a reduced one."""
+    cells = [replace(c, preset=preset) for c in DEFAULT_TPU_CELLS]
     engine = ClusterEngine(model, cells, seed=seed)
     if verbose:
         print("[serve] warming up cell executables ...")
@@ -61,9 +64,12 @@ def main():
     ap.add_argument("--rate", type=float, default=40.0)
     ap.add_argument("--qos-ms", type=float, default=200.0)
     ap.add_argument("--budget", type=int, default=12)
+    ap.add_argument("--preset", default="full", choices=["full", "smoke"])
     args = ap.parse_args()
+    enable_compile_cache()
     serve(model=args.model, n_queries=args.queries, rate_qps=args.rate,
-          qos_latency=args.qos_ms / 1e3, budget=args.budget)
+          qos_latency=args.qos_ms / 1e3, budget=args.budget,
+          preset=args.preset)
 
 
 if __name__ == "__main__":

@@ -79,10 +79,6 @@ def resolve_spec(spec, shape, mesh) -> P:
         r = _resolve(elem, mesh)
         if r is not None and dim % _axis_size(mesh, r) != 0:
             r = None
-        if isinstance(r, tuple) and len(r) == 1:
-            # normalize 1-tuples to bare axis names: this jax version's
-            # PartitionSpec treats P(("data",)) != P("data")
-            r = r[0]
         elems.append(r)
     return P(*elems)
 

@@ -232,14 +232,18 @@ def mtwnd_init(key, preset="smoke", dtype=jnp.float32):
             "wide": wide}
 
 
-def mtwnd_apply(params, batch, use_kernel=False):
-    """batch = {dense (B,dense), cat (B,n_tables,bag) int32} → (B, tasks)."""
+def mtwnd_apply(params, batch, use_kernel=False, interpret=False):
+    """batch = {dense (B,dense), cat (B,n_tables,bag) int32} → (B, tasks).
+
+    ``use_kernel`` routes the lookups through the Pallas embedding-bag
+    kernel; ``interpret`` runs that kernel in the Pallas interpreter (for
+    CPU tests only)."""
     feats = [batch["dense"]]
     for i, table in enumerate(params["tables"]):
         idx = batch["cat"][:, i]
         if use_kernel:
             from ..kernels import ops as kops
-            pooled = kops.embedding_bag(idx, table, interpret=True)
+            pooled = kops.embedding_bag(idx, table, interpret=interpret)
         else:
             pooled = table[idx].sum(axis=1)
         feats.append(pooled)
@@ -360,14 +364,20 @@ PAPER_MODELS = {
 }
 
 
+_VOCAB_PRESETS = {"mtwnd": MTWND_PRESETS, "dien": DIEN_PRESETS}
+
+
 def make_random_batch(model_name: str, preset: str, batch: int, seed: int = 0):
+    """Random inputs of the preset's shapes.  Categorical ids (MT-WND, DIEN)
+    are drawn uniformly from the preset's whole vocabulary, so a full-width
+    batch gathers rows from across its tables, not from a small corner."""
     spec = PAPER_MODELS[model_name].input_spec(preset, batch)
     key = jax.random.PRNGKey(seed)
     out = {}
     for name, s in spec.items():
         key, k = jax.random.split(key)
         if np.issubdtype(s.dtype, np.integer):
-            hi = {"candle": 2}.get(model_name, 100)
+            hi = _VOCAB_PRESETS[model_name][preset]["vocab"]
             out[name] = jax.random.randint(k, s.shape, 0, hi).astype(s.dtype)
         else:
             out[name] = jax.random.normal(k, s.shape, s.dtype)

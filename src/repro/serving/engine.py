@@ -89,18 +89,24 @@ class ClusterEngine:
         self.cell_types = list(cell_types)
         self.model = PAPER_MODELS[model_name]
         self.hedge_threshold = hedge_threshold
+        t0 = time.monotonic()
         key = jax.random.PRNGKey(seed)
         self._params = {}
         self._apply = {}
         for ct in cell_types:
             self._params[ct.name] = self.model.init(key, ct.preset)
             self._apply[ct.name] = jax.jit(self.model.apply)
+        jax.block_until_ready(self._params)
+        # Wall seconds spent building cell parameters and in ``warmup``:
+        # set-up that no measured service time includes.
+        self.setup_s = time.monotonic() - t0
         self.cells: list[ServingCell] = []
         self.records: list[QueryRecord] = []
 
     def warmup(self, max_batch: int = 32) -> None:
         """Pre-compile every (cell type × power-of-two bucket) executable so
         compile time never pollutes measured service latencies."""
+        t0 = time.monotonic()
         b = 1
         while b <= max_batch:
             for ct in self.cell_types:
@@ -108,6 +114,7 @@ class ClusterEngine:
                 out = self._apply[ct.name](self._params[ct.name], batch)
                 jax.block_until_ready(out)
             b *= 2
+        self.setup_s += time.monotonic() - t0
 
     # ------------------------------------------------------------- pool ops
     def configure(self, config) -> None:

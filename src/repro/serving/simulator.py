@@ -93,7 +93,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -734,9 +733,9 @@ def _sharded_counts_fn(n_dev: int, flavor: str, axis: str):
         # lanes shards its columns.
         out_specs = ((P("lane"), P("lane")) if axis == "w"
                      else (P(None, "lane"), P(None, "lane")))
-        fn = jax.jit(shard_map(base, mesh=_lane_mesh(n_dev),
-                               in_specs=in_specs, out_specs=out_specs,
-                               check_rep=False))
+        fn = jax.jit(jax.shard_map(base, mesh=_lane_mesh(n_dev),
+                                   in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False))
         _SHARDED_FNS[cache_key] = fn
     return fn
 

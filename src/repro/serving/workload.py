@@ -33,7 +33,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 
 @dataclass(frozen=True)
@@ -105,7 +104,7 @@ def _spec_chunk(k_arr, k_batch, c, base, rate, scale, p_a, p_b, max_batch, *,
     arrivals f32, batches i32).
 
     Every float expression carries an explicit float32 dtype — the caller
-    runs this under ``jax.experimental.enable_x64`` so the load-scale
+    runs this under ``jax.enable_x64`` so the load-scale
     division happens in float64 (matching the host path, which divides
     float64 arrivals before the device's float32 cast), and x64 mode flips
     jax's *default* dtypes, so nothing here may rely on them.  Chunk ``c``
@@ -184,7 +183,7 @@ class WorkloadSpec:
         else:
             p_a = self.mean_batch
             p_b = self.std_batch
-        with enable_x64():
+        with jax.enable_x64(True):
             return _spec_chunk(
                 k_arr, k_batch, np.int64(c), jnp.float32(base),
                 jnp.float32(self.rate_qps), jnp.float32(self.scale),

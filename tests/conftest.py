@@ -1,97 +1,13 @@
-"""Test-session bootstrap.
+"""Test-session bootstrap: one hypothesis profile for the property tests.
 
-The property tests use ``hypothesis``; the benchmark container does not ship
-it and installing packages is off-limits.  Instead of quarantining three test
-modules we register a minimal deterministic shim exposing the tiny slice of
-the hypothesis API the suite uses (``given``, ``settings``, ``strategies.
-floats/integers/tuples``).  The shim draws a fixed-seed random sample per
-example plus the strategy's boundary values, so the property tests still
-exercise edge cases reproducibly.  When the real hypothesis is importable it
-is used untouched.
+Their first example pays for JIT compilation, which takes far longer than
+hypothesis's per-example deadline, so the deadline is off.  Examples are
+derandomized and no example database is written, so every run (and every
+xdist worker) draws the same cases.
 """
 
-from __future__ import annotations
+from hypothesis import settings
 
-
-import importlib.util
-import sys
-import types
-
-import numpy as np
-
-
-def _build_hypothesis_shim() -> types.ModuleType:
-    class _Strategy:
-        def __init__(self, boundary, sampler):
-            self.boundary = list(boundary)   # deterministic edge examples
-            self.sampler = sampler           # rng -> one random example
-
-        def example(self, rng):
-            return self.sampler(rng)
-
-    def floats(min_value=0.0, max_value=1.0, **_):
-        lo, hi = float(min_value), float(max_value)
-        mid = lo + 0.5 * (hi - lo)
-        return _Strategy(
-            [lo, hi, mid],
-            lambda rng: float(rng.uniform(lo, np.nextafter(hi, np.inf))))
-
-    def integers(min_value=0, max_value=10, **_):
-        lo, hi = int(min_value), int(max_value)
-        return _Strategy([lo, hi],
-                         lambda rng: int(rng.integers(lo, hi + 1)))
-
-    def tuples(*strategies):
-        n_edges = max(len(s.boundary) for s in strategies)
-        boundary = [tuple(s.boundary[i % len(s.boundary)] for s in strategies)
-                    for i in range(n_edges)]
-        return _Strategy(
-            boundary,
-            lambda rng: tuple(s.example(rng) for s in strategies))
-
-    def settings(max_examples=100, **_):
-        def deco(fn):
-            fn._shim_max_examples = int(max_examples)
-            return fn
-        return deco
-
-    def given(*arg_strats, **kw_strats):
-        def deco(fn):
-            def wrapper():
-                n = getattr(fn, "_shim_max_examples", 50)
-                rng = np.random.default_rng(0)
-                strats = list(arg_strats) + list(kw_strats.values())
-                n_edges = max((len(s.boundary) for s in strats), default=0)
-                for i in range(max(n, n_edges)):
-                    if i < n_edges:
-                        vals = [s.boundary[i % len(s.boundary)]
-                                for s in strats]
-                    else:
-                        vals = [s.example(rng) for s in strats]
-                    args = vals[:len(arg_strats)]
-                    kwargs = dict(zip(kw_strats, vals[len(arg_strats):]))
-                    fn(*args, **kwargs)
-            # NB: no functools.wraps — pytest must see a zero-arg signature,
-            # not the parameterized one (it would treat params as fixtures).
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            return wrapper
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    st_mod = types.ModuleType("hypothesis.strategies")
-    st_mod.floats = floats
-    st_mod.integers = integers
-    st_mod.tuples = tuples
-    mod.strategies = st_mod
-    mod.__shim__ = True
-    return mod
-
-
-if importlib.util.find_spec("hypothesis") is None:
-    _shim = _build_hypothesis_shim()
-    sys.modules["hypothesis"] = _shim
-    sys.modules["hypothesis.strategies"] = _shim.strategies
+settings.register_profile("repro", deadline=None, derandomize=True,
+                          database=None)
+settings.load_profile("repro")

@@ -34,7 +34,7 @@ def test_mtwnd_kernel_embedding_parity():
     params = mtwnd_init(jax.random.PRNGKey(2), "smoke")
     batch = make_random_batch("mtwnd", "smoke", 4)
     plain = mtwnd_apply(params, batch, use_kernel=False)
-    kern = mtwnd_apply(params, batch, use_kernel=True)
+    kern = mtwnd_apply(params, batch, use_kernel=True, interpret=True)
     np.testing.assert_allclose(np.asarray(plain), np.asarray(kern),
                                rtol=1e-5, atol=1e-5)
 

@@ -33,7 +33,10 @@ def test_diverse_pool_beats_homogeneous_optimum():
     """Paper Fig. 9: the optimal heterogeneous configuration costs less than
     the optimal homogeneous configuration."""
     from repro.serving import best_homogeneous
-    ev, space, prof = make_paper_setup("mtwnd", seed=0, n_queries=1200)
+    # The default 1500-query stream (the verify anchor's): on the 1200-query
+    # prefix four homogeneous instances just meet the target, which ties the
+    # diverse optimum.
+    ev, space, prof = make_paper_setup("mtwnd", seed=0)
     cnt, homog_cost = best_homogeneous(ev, 0, space.prices, 0.99)
     assert cnt is not None
     best_cfg, best_cost, _ = ev.exhaustive(space, 0.99)
