@@ -1,0 +1,12 @@
+"""The benchmark's own tests: CPU only, at the cells' sizes or smaller.
+
+    PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q bench/tests
+"""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
