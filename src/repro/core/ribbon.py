@@ -41,6 +41,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from .acquisition import _NEG, select_batch
 from .gp import GaussianProcess
 from .objective import ribbon_objective
@@ -101,6 +102,7 @@ class RibbonOptimizer:
         self._best_obs_objective = 0.0
         # config -> masked EI score at selection time; consumed by tell.
         self._pending_ei: dict[tuple[int, ...], float] = {}
+        self.request = tracing.new_request()
 
     def _apply_cost_penalties(self) -> None:
         """(Re)build the lattice cost vector and the Eq. 2 normalizer from
@@ -150,6 +152,10 @@ class RibbonOptimizer:
         (possibly zero, setting ``exhausted``) when the open set runs out.
         Idempotent until the matching ``tell``s arrive.
         """
+        with tracing.span("ribbon.ask", self.request):
+            return self._ask_batch(q)
+
+    def _ask_batch(self, q: int) -> list[tuple[int, ...]]:
         if q <= 0:
             return []
         out: list[tuple[int, ...]] = []
@@ -168,21 +174,25 @@ class RibbonOptimizer:
         n_open = int(open_mask.sum()) - len(out)
         need = min(q - len(out), n_open)
         if need > 0:
-            x, y, mask = self.gp.buffers()
-            blocked = self._blocked()
-            if out:
-                init_idx = jnp.asarray(
-                    [self.space.index_of(c) for c in out], dtype=jnp.int32)
-                blocked = blocked.at[init_idx].set(True)
-            # The constant liar appends q-1 fake rows; clamp to the free GP
-            # buffer rows (q=1 never writes a row that survives the trace).
-            free_rows = self.gp.max_obs - self.gp.n_obs
-            q_eff = min(need, max(free_rows, 1))
-            picks, scores, _ = select_batch(
-                x, y, mask, self._lattice_dev, self.gp.denom,
-                float(self._best_obs_objective), blocked, self._weights_dev,
-                q_eff)
-            for idx, score in zip(np.asarray(picks), np.asarray(scores)):
+            with tracing.span("ribbon.select"):
+                x, y, mask = self.gp.buffers()
+                blocked = self._blocked()
+                if out:
+                    init_idx = jnp.asarray(
+                        [self.space.index_of(c) for c in out],
+                        dtype=jnp.int32)
+                    blocked = blocked.at[init_idx].set(True)
+                # The constant liar appends q-1 fake rows; clamp to the free
+                # GP buffer rows (q=1 never writes a row that survives the
+                # trace).
+                free_rows = self.gp.max_obs - self.gp.n_obs
+                q_eff = min(need, max(free_rows, 1))
+                picks, scores, _ = select_batch(
+                    x, y, mask, self._lattice_dev, self.gp.denom,
+                    float(self._best_obs_objective), blocked,
+                    self._weights_dev, q_eff)
+                picks, scores = np.asarray(picks), np.asarray(scores)
+            for idx, score in zip(picks, scores):
                 if score <= _NEG / 2:   # everything left was blocked
                     break
                 cfg = tuple(int(v) for v in self.lattice[int(idx)])
@@ -195,6 +205,10 @@ class RibbonOptimizer:
 
     # ----------------------------------------------------------------- tell
     def tell(self, config, qos_rate: float, estimated: bool = False) -> None:
+        with tracing.span("ribbon.tell", self.request):
+            self._tell(config, qos_rate, estimated)
+
+    def _tell(self, config, qos_rate: float, estimated: bool) -> None:
         config = tuple(int(v) for v in config)
         if self._init_queue and config == self._init_queue[0]:
             self._init_queue.pop(0)

@@ -88,10 +88,10 @@ subsequent window back at the QoS target.
 from __future__ import annotations
 
 import itertools
-import time
 
 import numpy as np
 
+from .. import tracing
 from ..core.ribbon import RibbonOptimizer
 from ..core.search_space import SearchSpace
 from ..serving.autoscaler import LoadMonitor, rescale
@@ -484,12 +484,12 @@ class ScenarioEngine:
         # rebuild by the re-anchor delta — the same continuity the planes'
         # advance_clock keeps for the carried pool state.
         ep_base = 0.0
-        t0 = time.perf_counter()
-        opt, used = self._initial_search(bounds, prices, dist0, f0)
+        with tracing.timed("scenario.search", kind="initial") as search:
+            opt, used = self._initial_search(bounds, prices, dist0, f0)
         if trace is not None:
-            trace.span("search:initial", 0.0, time.perf_counter() - t0,
+            trace.span("search:initial", 0.0, search.seconds,
                        args={"bo_evals": int(used),
-                             "wall_ms": (time.perf_counter() - t0) * 1e3})
+                             "wall_ms": search.seconds * 1e3})
         report.bo_evals += used
         config = self._pick_config(opt, bounds)
         plane.deploy(config)
@@ -505,12 +505,13 @@ class ScenarioEngine:
             if self._pending_switch and self._pending_switch[0] <= gq:
                 config = self._land_pending(config, gq, phase.load_factor)
             if restock_next:
-                t0 = time.perf_counter()
-                config, opt = self._restock(restock_next, p, gq, phase,
-                                            bounds, prices, config, opt,
-                                            report, pending)
+                with tracing.timed("scenario.search",
+                                   kind="restock") as search:
+                    config, opt = self._restock(restock_next, p, gq, phase,
+                                                bounds, prices, config, opt,
+                                                report, pending)
                 if trace is not None:
-                    wall = time.perf_counter() - t0
+                    wall = search.seconds
                     trace.span("search:restock", ep_base, wall,
                                args={"wall_ms": wall * 1e3,
                                      "config": [int(c) for c in config]})
@@ -539,12 +540,14 @@ class ScenarioEngine:
                     prev_cfg = config
                     ev_at = ep_base + float(
                         stream.arrivals[min(pos, phase.n_queries - 1)])
-                    t0 = time.perf_counter()
-                    config, opt, factor = self._apply_event(
-                        ev_spec, p, gq + pos, phase, factor, bounds, prices,
-                        config, opt, restock_next, report, pending)
+                    with tracing.timed("scenario.search",
+                                       kind=f"handle:{ev_spec.kind}") as search:
+                        config, opt, factor = self._apply_event(
+                            ev_spec, p, gq + pos, phase, factor, bounds,
+                            prices, config, opt, restock_next, report,
+                            pending)
                     if trace is not None:
-                        wall = time.perf_counter() - t0
+                        wall = search.seconds
                         trace.instant(f"event:{ev_spec.kind}", ev_at,
                                       tid=TID_EVENTS,
                                       args={"detail":
@@ -704,11 +707,12 @@ class ScenarioEngine:
                             bad_streak = 0
                             down_streak = 0
                             break
-                        t0 = time.perf_counter()
-                        opt, new_best, used = self._adapt_load(
-                            opt, self._scoring_dist(phase), est, kind)
+                        with tracing.timed("scenario.search",
+                                           kind=kind) as search:
+                            opt, new_best, used = self._adapt_load(
+                                opt, self._scoring_dist(phase), est, kind)
                         if trace is not None:
-                            wall = time.perf_counter() - t0
+                            wall = search.seconds
                             trace.span(f"search:{kind}", cut_at, wall,
                                        args={"bo_evals": int(used),
                                              "wall_ms": wall * 1e3,

@@ -13,6 +13,7 @@ from typing import ClassVar
 
 import numpy as np
 
+from .. import tracing
 from ..core.search_space import SearchSpace
 from .instance import (AWS_INSTANCES, MODEL_PROFILES, PAPER_POOLS,
                        InstanceType, ModelProfile)
@@ -50,6 +51,8 @@ class PoolEvaluator:
     def __post_init__(self):
         self.sim = PoolSimulator(self.model, self.types, self.workload,
                                  max_instances=self.max_instances)
+        # The evaluator's spans and its simulator's share one request id.
+        self.request = self.sim.request
         self._cache: dict[tuple[int, ...], float] = {}
         # (load_factor, config) -> rate for factors != 1.0; the unit factor
         # shares self._cache so grid sweeps and plain calls see one memo.
@@ -80,7 +83,8 @@ class PoolEvaluator:
         key = tuple(int(c) for c in config)
         cache, _ = self._caches_for(self._policy_key(policy))
         if key not in cache:
-            cache[key] = float(self.sim.qos(key, policy=policy).rates)
+            with tracing.span("pool.eval", self.request):
+                cache[key] = float(self.sim.qos(key, policy=policy).rates)
             self.n_evals += 1
         return cache[key]
 
@@ -121,13 +125,14 @@ class PoolEvaluator:
         cache, _ = self._caches_for(self._policy_key(policy))
         missing = [k for k in dict.fromkeys(keys) if k not in cache]
         if missing:
-            rates = []
-            for chunk, _, n in self._pow2_chunks(
-                    np.asarray(missing, dtype=np.int64)):
-                rates.append(self.sim.qos(chunk, policy=policy).rates[:n])
-            rates = np.concatenate(rates)
-            for k, r in zip(missing, rates):
-                cache[k] = float(r)
+            with tracing.span("pool.eval", self.request):
+                rates = []
+                for chunk, _, n in self._pow2_chunks(
+                        np.asarray(missing, dtype=np.int64)):
+                    rates.append(self.sim.qos(chunk, policy=policy).rates[:n])
+                rates = np.concatenate(rates)
+                for k, r in zip(missing, rates):
+                    cache[k] = float(r)
             self.n_evals += len(missing)
         return np.asarray([cache[k] for k in keys], dtype=np.float64)
 
@@ -174,26 +179,28 @@ class PoolEvaluator:
         ``_chunk``-bounded ``dispatch(chunk, rows)`` calls, so one rescale
         round costs one device round-trip whichever memo backs it.
         ``n_evals`` counts newly simulated cells only."""
-        keys = [tuple(int(c) for c in cfg) for cfg in configs]
-        factors = [float(f) for f in load_factors]
-        uniq_keys = list(dict.fromkeys(keys))
-        uniq_factors = list(dict.fromkeys(factors))
-        missing = {(f, k) for f in uniq_factors for k in uniq_keys
-                   if cell_get(f, k) is None}
-        if missing:
+        with tracing.span("pool.memo", self.request):
+            keys = [tuple(int(c) for c in cfg) for cfg in configs]
+            factors = [float(f) for f in load_factors]
+            uniq_keys = list(dict.fromkeys(keys))
+            uniq_factors = list(dict.fromkeys(factors))
+            missing = {(f, k) for f in uniq_factors for k in uniq_keys
+                       if cell_get(f, k) is None}
             cols = [k for k in uniq_keys if any((f, k) in missing
                                                 for f in uniq_factors)]
             rows = [f for f in uniq_factors if any((f, k) in missing
                                                    for k in cols)]
-            for chunk, i, n in self._pow2_chunks(
-                    np.asarray(cols, dtype=np.int64)):
-                rates = dispatch(chunk, rows)[:, :n]
+            chunks = list(self._pow2_chunks(np.asarray(cols, dtype=np.int64)))
+        for chunk, i, n in chunks:
+            rates = dispatch(chunk, rows)[:, :n]
+            with tracing.span("pool.memo", self.request):
                 for w, f in enumerate(rows):
                     for b, k in enumerate(cols[i:i + self._chunk]):
                         cell_put(f, k, float(rates[w, b]))
-            self.n_evals += len(missing)
-        return np.asarray([[cell_get(f, k) for k in keys]
-                           for f in factors], dtype=np.float64)
+        self.n_evals += len(missing)
+        with tracing.span("pool.memo", self.request):
+            return np.asarray([[cell_get(f, k) for k in keys]
+                               for f in factors], dtype=np.float64)
 
     def grid_from(self, state, configs, load_factors, *, deployed=None,
                   now=None, warmup=None, policy=None) -> np.ndarray:

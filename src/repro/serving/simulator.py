@@ -96,6 +96,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from .. import tracing
 from .instance import (InstanceType, ModelProfile,
                        bucketed_service_time_lut, service_table_for,
                        service_time_lut, service_time_table)
@@ -1079,8 +1080,18 @@ def _warn_deprecated(name: str, alt: str) -> None:
         DeprecationWarning, stacklevel=3)
 
 
+def _fetch(x, lane: str, request: int | None):
+    """A lane's device result on the host: the host blocks on the device
+    (span ``sim.wait``)."""
+    with tracing.span("sim.wait", request, lane=lane):
+        return jax.device_get(x)
+
+
 class PoolSimulator:
-    """Simulator bound to (model profile, instance type order, workload)."""
+    """Simulator bound to (model profile, instance type order, workload).
+
+    Host work a lane does before its dispatch is traced as ``sim.stage``,
+    and the wait for its result as ``sim.wait`` (``repro.tracing``)."""
 
     def __init__(self, model: ModelProfile, types: list[InstanceType],
                  workload: Workload, max_instances: int = 40):
@@ -1108,6 +1119,7 @@ class PoolSimulator:
         # _grid_arrs is LRU (hits refresh recency, see _grid_arr_shards).
         self._grid_consts: dict[tuple, tuple] = {}
         self._grid_arrs: dict[tuple, jnp.ndarray] = {}
+        self.request = tracing.new_request()
 
     def _slots_batch(self, configs) -> tuple[np.ndarray, np.ndarray]:
         """Config→slot expansion for a (B, n_types) batch (see
@@ -1324,18 +1336,21 @@ class PoolSimulator:
         """Per-query end-to-end latency (wait + service) for a pool config."""
         if sum(int(c) for c in config) == 0:
             return np.full(self.workload.n_queries, np.inf)
-        type_of_slot, active = self._slots(config)
-        free0 = jnp.asarray(_cold_free0(active))
-        if policy is None:
-            _, (lat, _, _) = _simulate_scan(self._arrivals, self._service,
-                                            jnp.asarray(type_of_slot),
-                                            self._priority, free0)
-        else:
-            pref, aff, hed = self._policy_single_args(policy, type_of_slot)
-            _, (lat, _, _) = _simulate_scan_policy(
-                self._arrivals, self._service, jnp.asarray(type_of_slot),
-                self._priority, free0, pref, aff, hed)
-        return np.asarray(jax.device_get(lat), dtype=np.float64)
+        with tracing.span("sim.stage", self.request, lane="single"):
+            type_of_slot, active = self._slots(config)
+            free0 = jnp.asarray(_cold_free0(active))
+            if policy is None:
+                _, (lat, _, _) = _simulate_scan(
+                    self._arrivals, self._service, jnp.asarray(type_of_slot),
+                    self._priority, free0)
+            else:
+                pref, aff, hed = self._policy_single_args(policy,
+                                                          type_of_slot)
+                _, (lat, _, _) = _simulate_scan_policy(
+                    self._arrivals, self._service, jnp.asarray(type_of_slot),
+                    self._priority, free0, pref, aff, hed)
+        return np.asarray(_fetch(lat, "single", self.request),
+                          dtype=np.float64)
 
     def _lat_waits_single(self, config,
                           policy) -> tuple[np.ndarray, np.ndarray]:
@@ -1789,39 +1804,42 @@ class PoolSimulator:
             if telemetry:
                 tel = Telemetry.zeros(len(self.types), shape[:-1])
             return np.zeros(shape, dtype=np.float64), tel
-        type_of_slot, active = self._slots_batch(configs)
-        free0 = _cold_free0(active)
-        start = packed = None
-        if policy is None:
-            zero = configs.sum(axis=1) == 0
-            if telemetry:
-                tos_d, prio, fr0_d, n_act, iota, _ = self._tel_operands(
-                    type_of_slot, active, free0)
-                _, (lat, start, packed) = _scan_tel_batch(
-                    self._arrivals, self._service, tos_d, prio, fr0_d,
-                    n_act, iota)
+        with tracing.span("sim.stage", self.request, lane="batch"):
+            type_of_slot, active = self._slots_batch(configs)
+            free0 = _cold_free0(active)
+            start = packed = None
+            if policy is None:
+                zero = configs.sum(axis=1) == 0
+                if telemetry:
+                    tos_d, prio, fr0_d, n_act, iota, _ = self._tel_operands(
+                        type_of_slot, active, free0)
+                    _, (lat, start, packed) = _scan_tel_batch(
+                        self._arrivals, self._service, tos_d, prio, fr0_d,
+                        n_act, iota)
+                else:
+                    _, (lat, _, _) = _simulate_scan_batch(
+                        self._arrivals, self._service,
+                        jnp.asarray(type_of_slot), self._priority,
+                        jnp.asarray(free0))
             else:
-                _, (lat, _, _) = _simulate_scan_batch(
-                    self._arrivals, self._service, jnp.asarray(type_of_slot),
-                    self._priority, jnp.asarray(free0))
-        else:
-            tos, fr0, pref, aff, hed, n_p = _fold_policy(policy,
-                                                         type_of_slot, free0)
-            zero = np.tile(configs.sum(axis=1) == 0, n_p)
-            if telemetry:
-                active_l = np.tile(active, (n_p, 1))
-                tos_d, prio, fr0_d, n_act, iota, width = self._tel_operands(
-                    tos, active_l, fr0)
-                _, (lat, start, packed) = _scan_policy_tel_batch(
-                    self._arrivals, self._service, tos_d, prio, fr0_d,
-                    jnp.asarray(np.ascontiguousarray(pref[:, :width])),
-                    jnp.asarray(aff), jnp.asarray(hed), n_act, iota)
-            else:
-                _, (lat, _, _) = _scan_policy_batch(
-                    self._arrivals, self._service, jnp.asarray(tos),
-                    self._priority, jnp.asarray(fr0), jnp.asarray(pref),
-                    jnp.asarray(aff), jnp.asarray(hed))
-        out = np.asarray(jax.device_get(lat), dtype=np.float64)
+                tos, fr0, pref, aff, hed, n_p = _fold_policy(
+                    policy, type_of_slot, free0)
+                zero = np.tile(configs.sum(axis=1) == 0, n_p)
+                if telemetry:
+                    active_l = np.tile(active, (n_p, 1))
+                    tos_d, prio, fr0_d, n_act, iota, width = (
+                        self._tel_operands(tos, active_l, fr0))
+                    _, (lat, start, packed) = _scan_policy_tel_batch(
+                        self._arrivals, self._service, tos_d, prio, fr0_d,
+                        jnp.asarray(np.ascontiguousarray(pref[:, :width])),
+                        jnp.asarray(aff), jnp.asarray(hed), n_act, iota)
+                else:
+                    _, (lat, _, _) = _scan_policy_batch(
+                        self._arrivals, self._service, jnp.asarray(tos),
+                        self._priority, jnp.asarray(fr0), jnp.asarray(pref),
+                        jnp.asarray(aff), jnp.asarray(hed))
+        out = np.asarray(_fetch(lat, "batch", self.request),
+                         dtype=np.float64)
         out[zero, :] = np.inf
         if stacked:
             out = out.reshape(n_p, n_b, n)
@@ -2012,37 +2030,39 @@ class PoolSimulator:
         count arithmetic, constant memory — only the counters cross back to
         the host.  The second element is None otherwise.
         """
-        arrivals = self._stacked_arrivals(load_factors)
-        n_w = len(arrivals)
-        n_b = len(configs)
-        tables = self._stacked_service(service_tables, n_w)
-        stacked = policy is not None and policy.stacked
-        n_p = policy.n_policies if stacked else 1
-        if configs.size == 0 or self.workload.n_queries == 0:
-            if configs.size:
-                self._slots_batch(configs)  # keep shape/padding validation
-            shape = (n_w, n_p, n_b) if stacked else (n_w, n_b)
-            tel = (Telemetry.zeros(len(self.types), shape)
-                   if telemetry else None)
-            if self.workload.n_queries == 0 and configs.size:
-                # 0/0 convention: an empty stream has no violations.
-                return np.full(shape, np.nan, dtype=np.float64), tel
-            return np.zeros(shape, dtype=np.float64), tel
-        type_of_slot, active = self._slots_batch(configs)
-        if states is not None:
-            if len(states) != n_w:
-                raise ValueError(f"states= needs one entry per workload row "
-                                 f"({n_w}), got {len(states)}")
-            free0 = self._states_free0(states, configs, active, arrivals,
-                                       warmup)
-        elif state is None:
-            free0 = _cold_free0(active)
-        else:
-            free_mat = self._warm_free_matrix(state, configs, deployed, now,
-                                              warmup)
-            free0 = self._warm_free0_rows(
-                state, free_mat, active, float(arrivals[:, -1].max()),
-                "warm-start grid")
+        with tracing.span("sim.stage", self.request, lane="grid"):
+            arrivals = self._stacked_arrivals(load_factors)
+            n_w = len(arrivals)
+            n_b = len(configs)
+            tables = self._stacked_service(service_tables, n_w)
+            stacked = policy is not None and policy.stacked
+            n_p = policy.n_policies if stacked else 1
+            if configs.size == 0 or self.workload.n_queries == 0:
+                if configs.size:
+                    # Keep shape/padding validation.
+                    self._slots_batch(configs)
+                shape = (n_w, n_p, n_b) if stacked else (n_w, n_b)
+                tel = (Telemetry.zeros(len(self.types), shape)
+                       if telemetry else None)
+                if self.workload.n_queries == 0 and configs.size:
+                    # 0/0 convention: an empty stream has no violations.
+                    return np.full(shape, np.nan, dtype=np.float64), tel
+                return np.zeros(shape, dtype=np.float64), tel
+            type_of_slot, active = self._slots_batch(configs)
+            if states is not None:
+                if len(states) != n_w:
+                    raise ValueError(f"states= needs one entry per workload "
+                                     f"row ({n_w}), got {len(states)}")
+                free0 = self._states_free0(states, configs, active,
+                                           arrivals, warmup)
+            elif state is None:
+                free0 = _cold_free0(active)
+            else:
+                free_mat = self._warm_free_matrix(state, configs, deployed,
+                                                  now, warmup)
+                free0 = self._warm_free0_rows(
+                    state, free_mat, active, float(arrivals[:, -1].max()),
+                    "warm-start grid")
         tel = None
         if telemetry:
             counts, tel = self._qos_counts_grid_tel(
@@ -2075,73 +2095,75 @@ class PoolSimulator:
         and both combined — shards across the host devices through
         ``_dispatch_grid_sharded`` when several are configured; the per-row
         ``states=`` carries run the single-device states jits."""
-        width = self._grid_slot_pad(configs.sum(axis=1))
-        arr = np.asarray(arrivals, np.float32)                # (W, nq)
-        tos = np.ascontiguousarray(type_of_slot[:, :width])   # (B, S)
-        free0 = np.ascontiguousarray(free0_rows[..., :width])
-        per_row = free0.ndim == 3                             # (W, B, S)
+        with tracing.span("sim.stage", self.request, lane="grid"):
+            width = self._grid_slot_pad(configs.sum(axis=1))
+            arr = np.asarray(arrivals, np.float32)                # (W, nq)
+            tos = np.ascontiguousarray(type_of_slot[:, :width])   # (B, S)
+            free0 = np.ascontiguousarray(free0_rows[..., :width])
+            per_row = free0.ndim == 3                             # (W, B, S)
 
-        qos_t = jnp.float32(_qos_threshold_f32(self.model.qos_latency))
-        iota = jnp.arange(width, dtype=jnp.int32)
-        policy_ops = None
-        if policy is not None:
-            if per_row:
-                # Fold the policy over the layout alone, then tile every
-                # row's carries across the policy axis (the carry does not
-                # depend on the policy).
-                tos2, _, pref, aff, hed, n_p = _fold_policy(
-                    policy, tos, np.zeros_like(tos, dtype=np.float32))
-                free0 = np.ascontiguousarray(np.tile(free0, (1, n_p, 1)))
-                tos = tos2
-            else:
-                tos, free0, pref, aff, hed, _ = _fold_policy(policy, tos,
-                                                             free0)
-            policy_ops = (np.asarray(pref), np.asarray(aff), np.asarray(hed))
-        if per_row:
-            ops = (jnp.asarray(arr),
-                   self._service.T if tables is None
-                   else jnp.transpose(tables, (0, 2, 1)),
-                   jnp.asarray(tos), self._priority[:width],
-                   jnp.asarray(free0), iota, qos_t)
+            qos_t = jnp.float32(_qos_threshold_f32(self.model.qos_latency))
+            iota = jnp.arange(width, dtype=jnp.int32)
+            policy_ops = None
             if policy is not None:
-                ops = ops + tuple(jnp.asarray(x) for x in policy_ops)
-                fn = (_grid_counts_policy_states_jit if tables is None
-                      else _grid_counts_policy_tables_states_jit)
+                if per_row:
+                    # Fold the policy over the layout alone, then tile every
+                    # row's carries across the policy axis (the carry does
+                    # not depend on the policy).
+                    tos2, _, pref, aff, hed, n_p = _fold_policy(
+                        policy, tos, np.zeros_like(tos, dtype=np.float32))
+                    free0 = np.ascontiguousarray(np.tile(free0, (1, n_p, 1)))
+                    tos = tos2
+                else:
+                    tos, free0, pref, aff, hed, _ = _fold_policy(policy, tos,
+                                                                 free0)
+                policy_ops = (np.asarray(pref), np.asarray(aff),
+                              np.asarray(hed))
+            n_dev = jax.local_device_count()
+            counts = None
+            if per_row:
+                ops = (jnp.asarray(arr),
+                       self._service.T if tables is None
+                       else jnp.transpose(tables, (0, 2, 1)),
+                       jnp.asarray(tos), self._priority[:width],
+                       jnp.asarray(free0), iota, qos_t)
+                if policy is not None:
+                    ops = ops + tuple(jnp.asarray(x) for x in policy_ops)
+                    fn = (_grid_counts_policy_states_jit if tables is None
+                          else _grid_counts_policy_tables_states_jit)
+                else:
+                    fn = (_grid_counts_states_jit if tables is None
+                          else _grid_counts_tables_states_jit)
+                counts, _ = fn(*ops)
+            elif n_dev > 1:
+                factors = tuple(float(f) for f in np.asarray(load_factors,
+                                                             dtype=np.float64))
+            elif policy is not None:
+                pref, aff, hed = (jnp.asarray(x) for x in policy_ops)
+                if tables is not None:
+                    counts, _ = _grid_counts_policy_tables_jit(
+                        jnp.asarray(arr), jnp.transpose(tables, (0, 2, 1)),
+                        jnp.asarray(tos), self._priority[:width],
+                        jnp.asarray(free0), iota, qos_t, pref, aff, hed)
+                else:
+                    counts, _ = _grid_counts_policy_jit(
+                        jnp.asarray(arr), self._service.T, jnp.asarray(tos),
+                        self._priority[:width], jnp.asarray(free0), iota,
+                        qos_t, pref, aff, hed)
+            elif tables is not None:
+                counts, _ = _grid_counts_tables_jit(
+                    jnp.asarray(arr), jnp.transpose(tables, (0, 2, 1)),
+                    jnp.asarray(tos), self._priority[:width],
+                    jnp.asarray(free0), iota, qos_t)
             else:
-                fn = (_grid_counts_states_jit if tables is None
-                      else _grid_counts_tables_states_jit)
-            counts, _ = fn(*ops)
-            return np.asarray(jax.device_get(counts))
-        n_dev = jax.local_device_count()
-        if n_dev > 1:
-            factors = tuple(float(f) for f in np.asarray(load_factors,
-                                                         dtype=np.float64))
+                counts, _ = _grid_counts_jit(
+                    jnp.asarray(arr), self._service.T, jnp.asarray(tos),
+                    self._priority[:width], jnp.asarray(free0), iota, qos_t)
+        if counts is None:
             return self._dispatch_grid_sharded(arr, tables, tos, free0,
                                                width, n_dev, factors,
                                                policy_ops)
-        if policy is not None:
-            pref, aff, hed = (jnp.asarray(x) for x in policy_ops)
-            if tables is not None:
-                counts, _ = _grid_counts_policy_tables_jit(
-                    jnp.asarray(arr), jnp.transpose(tables, (0, 2, 1)),
-                    jnp.asarray(tos), self._priority[:width],
-                    jnp.asarray(free0), iota, qos_t, pref, aff, hed)
-            else:
-                counts, _ = _grid_counts_policy_jit(
-                    jnp.asarray(arr), self._service.T, jnp.asarray(tos),
-                    self._priority[:width], jnp.asarray(free0), iota, qos_t,
-                    pref, aff, hed)
-            return np.asarray(jax.device_get(counts))
-        if tables is not None:
-            counts, _ = _grid_counts_tables_jit(
-                jnp.asarray(arr), jnp.transpose(tables, (0, 2, 1)),
-                jnp.asarray(tos), self._priority[:width],
-                jnp.asarray(free0), iota, qos_t)
-            return np.asarray(jax.device_get(counts))
-        counts, _ = _grid_counts_jit(
-            jnp.asarray(arr), self._service.T, jnp.asarray(tos),
-            self._priority[:width], jnp.asarray(free0), iota, qos_t)
-        return np.asarray(jax.device_get(counts))
+        return np.asarray(_fetch(counts, "grid", self.request))
 
     def _qos_counts_grid_tel(self, arrivals, tables, type_of_slot,
                              free0_rows, configs, policy,
@@ -2259,45 +2281,45 @@ class PoolSimulator:
         so counts are bit-identical to them.
         """
         n_w, n_b = len(arr), len(tos)
-        service_r, prio_r, iota_r, qos_r = self._grid_replicated_consts(
-            width, n_dev)
-        if tables is None:
-            flavor = "plain" if policy_ops is None else "policy"
-            svc = service_r
-        else:
-            flavor = "tables" if policy_ops is None else "policy_tables"
-            svc = jnp.transpose(tables, (0, 2, 1))
+        with tracing.span("sim.stage", self.request, lane="grid"):
+            service_r, prio_r, iota_r, qos_r = self._grid_replicated_consts(
+                width, n_dev)
+            if tables is None:
+                flavor = "plain" if policy_ops is None else "policy"
+                svc = service_r
+            else:
+                flavor = "tables" if policy_ops is None else "policy_tables"
+                svc = jnp.transpose(tables, (0, 2, 1))
 
-        # Split whichever axis wastes fewer lanes per device; both axes pad
-        # cyclically (duplicate levels / duplicate lanes, results of the
-        # pad rows dropped), so neither split requires exact divisibility.
-        pad_w = (-n_w) % n_dev
-        pad_b = (-n_b) % n_dev
-        lanes_w_split = ((n_w + pad_w) // n_dev) * n_b
-        lanes_b_split = n_w * ((n_b + pad_b) // n_dev)
-        extra = () if policy_ops is None else policy_ops
-        if lanes_b_split < lanes_w_split:
-            if pad_b:
-                idx = np.arange(n_b + pad_b) % n_b
-                tos, free0 = tos[idx], free0[idx]
-                # Policy operands (pref rows, affinity, hedge) all carry the
-                # lane axis leading, so they pad with the same cyclic index.
-                extra = tuple(x[idx] for x in extra)
-            fn = _sharded_counts_fn(n_dev, flavor, "b")
+            # Split whichever axis wastes fewer lanes per device; both axes
+            # pad cyclically (duplicate levels / duplicate lanes, results of
+            # the pad rows dropped), so neither split requires exact
+            # divisibility.
+            pad_w = (-n_w) % n_dev
+            pad_b = (-n_b) % n_dev
+            lanes_w_split = ((n_w + pad_w) // n_dev) * n_b
+            lanes_b_split = n_w * ((n_b + pad_b) // n_dev)
+            extra = () if policy_ops is None else policy_ops
+            split_b = lanes_b_split < lanes_w_split
+            if split_b:
+                if pad_b:
+                    idx = np.arange(n_b + pad_b) % n_b
+                    tos, free0 = tos[idx], free0[idx]
+                    # Policy operands (pref rows, affinity, hedge) all carry
+                    # the lane axis leading, so they pad with the same
+                    # cyclic index.
+                    extra = tuple(x[idx] for x in extra)
+            elif pad_w and tables is not None:
+                idx = np.arange(n_w + pad_w) % n_w
+                svc = jnp.concatenate([svc, svc[idx[n_w:]]])
+            mode = "b" if split_b else "w"
+            fn = _sharded_counts_fn(n_dev, flavor, mode)
             counts, _ = fn(
-                self._grid_arr_shards(arr, "b", n_dev, factors), svc,
+                self._grid_arr_shards(arr, mode, n_dev, factors), svc,
                 jnp.asarray(tos), prio_r, jnp.asarray(free0), iota_r, qos_r,
                 *(jnp.asarray(x) for x in extra))
-            return np.asarray(jax.device_get(counts))[:, :n_b]
-        if pad_w and tables is not None:
-            idx = np.arange(n_w + pad_w) % n_w
-            svc = jnp.concatenate([svc, svc[idx[n_w:]]])
-        fn = _sharded_counts_fn(n_dev, flavor, "w")
-        counts, _ = fn(
-            self._grid_arr_shards(arr, "w", n_dev, factors), svc,
-            jnp.asarray(tos), prio_r, jnp.asarray(free0), iota_r, qos_r,
-            *(jnp.asarray(x) for x in extra))
-        return np.asarray(jax.device_get(counts))[:n_w]
+        counts = np.asarray(_fetch(counts, "grid", self.request))
+        return counts[:, :n_b] if split_b else counts[:n_w]
 
 
 @dataclass(frozen=True)
@@ -2355,6 +2377,7 @@ class StreamingSimulator:
         # f32 values the monolithic path's service-table cast produces.
         self._lut_T = jnp.asarray(np.asarray(lut, dtype=np.float32).T)
         self._priority = jnp.arange(max_instances, dtype=jnp.float32)
+        self.request = tracing.new_request()
 
     def qos(self, config, n_queries: int, *, probe=None) -> StreamResult:
         """Stream ``n_queries`` of the bound spec through ``config``.
@@ -2396,21 +2419,24 @@ class StreamingSimulator:
         shift = 0.0
         rebases = 0
         for c in range(math.ceil(n / chunk)):
-            if self._bucketed:
-                arr, local, batches, bucket = self.spec.generate_chunk(
-                    c, base)
-                batches = bucket * self._lut_stride + batches
-            else:
-                arr, local, batches = self.spec.generate_chunk(c, base)
-            left = n - c * chunk
-            if left >= chunk:
-                valid = full_valid
-            else:
-                valid = np.zeros(chunk, dtype=bool)
-                valid[:left] = True
-            free, count = _stream_chunk_jit(
-                free, count, jnp.float32(shift), arr, batches,
-                jnp.asarray(valid), self._lut_T, tos, prio, iota, qos_t)
+            # The host's part of a chunk: the device has nothing queued
+            # until the chunk's scan is dispatched.
+            with tracing.span("sim.stream_draw", self.request):
+                if self._bucketed:
+                    arr, local, batches, bucket = self.spec.generate_chunk(
+                        c, base)
+                    batches = bucket * self._lut_stride + batches
+                else:
+                    arr, local, batches = self.spec.generate_chunk(c, base)
+                left = n - c * chunk
+                if left >= chunk:
+                    valid = full_valid
+                else:
+                    valid = np.zeros(chunk, dtype=bool)
+                    valid[:left] = True
+                free, count = _stream_chunk_jit(
+                    free, count, jnp.float32(shift), arr, batches,
+                    jnp.asarray(valid), self._lut_T, tos, prio, iota, qos_t)
             shift = 0.0
             base = float(local[-1])
             horizon = base / scale
@@ -2429,5 +2455,6 @@ class StreamingSimulator:
                 rebases += 1
             if probe is not None:
                 probe(c)
-        return StreamResult(rate=int(jax.device_get(count)) / n,
+        count = int(_fetch(count, "stream", self.request))
+        return StreamResult(rate=count / n,
                             n_queries=n, rebases=rebases)
