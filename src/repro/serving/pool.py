@@ -168,17 +168,22 @@ class PoolEvaluator:
                     grid_cache[(f, k)] = rate
         return self._sweep_grid(
             configs, load_factors, cell_get, cell_put,
-            lambda chunk, rows: self.sim.qos(chunk, workloads=rows,
-                                             policy=policy).rates)
+            lambda chunk, rows: self.sim._qos_grid_issue(chunk, rows,
+                                                         policy=policy))
 
     def _sweep_grid(self, configs, load_factors, cell_get, cell_put,
-                    dispatch) -> np.ndarray:
+                    issue) -> np.ndarray:
         """Shared memoized (load level × config) sweep behind ``grid`` and
         ``grid_from``: misses are evaluated as a cross product — every load
         level with any miss × every config missing somewhere — in
-        ``_chunk``-bounded ``dispatch(chunk, rows)`` calls, so one rescale
-        round costs one device round-trip whichever memo backs it.
-        ``n_evals`` counts newly simulated cells only."""
+        ``_chunk``-bounded ``issue(chunk, rows)`` grid dispatches, so one
+        rescale round costs one device round-trip whichever memo backs it.
+
+        The dispatches are disjoint column blocks of one grid, so every one
+        is issued before the first is fetched: the device scans the queued
+        ones back to back while the host stages the next.  They are then
+        fetched in order, each followed by its memo writes.  ``n_evals``
+        counts newly simulated cells only."""
         with tracing.span("pool.memo", self.request):
             keys = [tuple(int(c) for c in cfg) for cfg in configs]
             factors = [float(f) for f in load_factors]
@@ -191,8 +196,10 @@ class PoolEvaluator:
             rows = [f for f in uniq_factors if any((f, k) in missing
                                                    for k in cols)]
             chunks = list(self._pow2_chunks(np.asarray(cols, dtype=np.int64)))
-        for chunk, i, n in chunks:
-            rates = dispatch(chunk, rows)[:, :n]
+        pending = [issue(chunk, rows) for chunk, _, _ in chunks]
+        for j, (_, i, n) in enumerate(chunks):
+            rates = self.sim._qos_grid_fetch(
+                pending[j], queued=len(chunks) - 1 - j)[:, :n]
             with tracing.span("pool.memo", self.request):
                 for w, f in enumerate(rows):
                     for b, k in enumerate(cols[i:i + self._chunk]):
@@ -238,9 +245,9 @@ class PoolEvaluator:
             configs, load_factors,
             lambda f, k: cache.get((f, k)),
             lambda f, k, rate: cache.__setitem__((f, k), rate),
-            lambda chunk, rows: self.sim.qos(
-                chunk, workloads=rows, state=state, deployed=deployed,
-                now=now, warmup=warmup, policy=policy).rates)
+            lambda chunk, rows: self.sim._qos_grid_issue(
+                chunk, rows, state=state, deployed=deployed, now=now,
+                warmup=warmup, policy=policy))
 
     def exhaustive(self, space: SearchSpace, qos_target: float,
                    load_factor: float = 1.0, *, policy=None):

@@ -1080,11 +1080,28 @@ def _warn_deprecated(name: str, alt: str) -> None:
         DeprecationWarning, stacklevel=3)
 
 
-def _fetch(x, lane: str, request: int | None):
+def _fetch(x, lane: str, request: int | None, **args):
     """A lane's device result on the host: the host blocks on the device
-    (span ``sim.wait``)."""
-    with tracing.span("sim.wait", request, lane=lane):
+    (span ``sim.wait``, with ``args`` besides the lane)."""
+    with tracing.span("sim.wait", request, lane=lane, **args):
         return jax.device_get(x)
+
+
+@dataclass(frozen=True)
+class _GridPending:
+    """A grid QoS dispatch issued and not yet fetched
+    (``PoolSimulator._qos_grid_issue``): the device's (W, L) counts, the
+    real block of them (``[:n_w, :n_l]``: the sharded path pads load
+    levels or lanes) and the shape of the rates.  Where the rates needed
+    no wait here (an empty grid, or the telemetry twin, which fetches its
+    own counts), they are in ``rates`` and ``counts`` is None."""
+
+    counts: object
+    n_w: int
+    n_l: int
+    shape: tuple
+    rates: np.ndarray | None = None
+    telemetry: "Telemetry | None" = None
 
 
 class PoolSimulator:
@@ -1254,28 +1271,20 @@ class PoolSimulator:
         multi-phase sweep runs warm in one dispatch.  Mutually exclusive
         with the single shared ``state=`` and with ``telemetry=``.
         """
+        if workloads is not None:
+            pending = self._qos_grid_issue(
+                configs, workloads, service_tables=service_tables,
+                policy=policy, state=state, states=states,
+                deployed=deployed, now=now, warmup=warmup,
+                telemetry=telemetry)
+            return QosResult(rates=self._qos_grid_fetch(pending), state=None,
+                             telemetry=pending.telemetry)
         policy = self._check_policy(policy)
         if states is not None:
-            if workloads is None:
-                raise ValueError("states= is a per-workload-row grid axis; "
-                                 "pass workloads= as well")
-            if state is not None or deployed is not None or now is not None:
-                raise ValueError("states= carries its own (state, deployed) "
-                                 "pairs; state=/deployed=/now= do not apply")
-            if telemetry:
-                raise ValueError("telemetry is not supported on the "
-                                 "per-row states= grid")
-        else:
-            self._check_warm_kwargs(state, deployed, now, warmup)
+            raise ValueError("states= is a per-workload-row grid axis; "
+                             "pass workloads= as well")
+        self._check_warm_kwargs(state, deployed, now, warmup)
         cfg = np.asarray(configs, dtype=np.int64)
-        if workloads is not None:
-            if cfg.ndim != 2:
-                raise ValueError("the workload grid needs a (B, n_types) "
-                                 "config batch")
-            rates, tel = self._qos_grid(cfg, workloads, service_tables,
-                                        policy, state, deployed, now, warmup,
-                                        telemetry, states=states)
-            return QosResult(rates=rates, state=None, telemetry=tel)
         if service_tables is not None:
             raise ValueError("service_tables is a workload-grid axis; pass "
                              "workloads= as well")
@@ -2004,32 +2013,53 @@ class PoolSimulator:
         width = max(8, 1 << (need - 1).bit_length())
         return min(width, self.max_instances)
 
-    def _qos_grid(self, configs, load_factors, service_tables, policy,
-                  state, deployed, now, warmup, telemetry: bool = False,
-                  states=None) -> tuple[np.ndarray, "Telemetry | None"]:
-        """QoS-rate grid core: (W, B) float64 — or (W, P, B) under a
-        stacked policy — where cell ``[w, b]`` equals ``PoolSimulator(...,
+    def _qos_grid_issue(self, configs, load_factors, *, service_tables=None,
+                        policy=None, state=None, states=None, deployed=None,
+                        now=None, warmup=None,
+                        telemetry: bool = False) -> _GridPending:
+        """``qos(configs, workloads=load_factors, ...)``'s grid lane up to
+        its dispatch: validates, stages and dispatches, and returns the
+        pending dispatch that ``_qos_grid_fetch`` turns into the rates —
+        (W, B) float64, or (W, P, B) under a stacked policy, where cell
+        ``[w, b]`` equals ``PoolSimulator(...,
         workload.scaled(load_factors[w]))``'s single-lane rate for
         ``configs[b]`` exactly.  This is the fused fast path: the lean
         count scan (see ``_grid_lane_qos_counts``) over nested (workload,
         config) axes, sharded across XLA host devices when several are
         configured, with only the int32 counts crossing back to the host.
 
+        Nothing here waits on the device, so a caller may issue several
+        dispatches before fetching the first (``PoolEvaluator``'s sweep
+        does): the host stages the next while the device scans.  No host
+        buffer handed to a dispatch is written again.
+
         ``service_tables`` (optional, (W, n_types, n_queries)) stacks one
         service table per workload row — phases with *different batch
-        distributions* share the dispatch.  Stacked-table and policy
-        flavors run the single-device executable: per-row tables and
-        routing sweeps are scenario/bench axes, not the BO rescale hot
-        loop.  Warm carries (``state=``) remap per candidate exactly as
-        the batch lane; the rounded-down float32 threshold (see
-        ``_qos_threshold_f32``) keeps device counts bit-compatible with
-        the host comparison either way.
+        distributions* share the dispatch.  Warm carries (``state=``)
+        remap per candidate exactly as the batch lane; the rounded-down
+        float32 threshold (see ``_qos_threshold_f32``) keeps device counts
+        bit-compatible with the host comparison either way.
 
         With ``telemetry`` the sweep runs the in-carry accumulator kernels
         (``_grid_lane_qos_counts_tel``): same dispatch recurrence, same
         count arithmetic, constant memory — only the counters cross back to
-        the host.  The second element is None otherwise.
+        the host.  That twin fetches at once, and its telemetry rides the
+        pending dispatch.
         """
+        policy = self._check_policy(policy)
+        if states is not None:
+            if state is not None or deployed is not None or now is not None:
+                raise ValueError("states= carries its own (state, deployed) "
+                                 "pairs; state=/deployed=/now= do not apply")
+            if telemetry:
+                raise ValueError("telemetry is not supported on the "
+                                 "per-row states= grid")
+        else:
+            self._check_warm_kwargs(state, deployed, now, warmup)
+        configs = np.asarray(configs, dtype=np.int64)
+        if configs.ndim != 2:
+            raise ValueError("the workload grid needs a (B, n_types) "
+                             "config batch")
         with tracing.span("sim.stage", self.request, lane="grid"):
             arrivals = self._stacked_arrivals(load_factors)
             n_w = len(arrivals)
@@ -2037,17 +2067,19 @@ class PoolSimulator:
             tables = self._stacked_service(service_tables, n_w)
             stacked = policy is not None and policy.stacked
             n_p = policy.n_policies if stacked else 1
+            shape = (n_w, n_p, n_b) if stacked else (n_w, n_b)
             if configs.size == 0 or self.workload.n_queries == 0:
                 if configs.size:
                     # Keep shape/padding validation.
                     self._slots_batch(configs)
-                shape = (n_w, n_p, n_b) if stacked else (n_w, n_b)
                 tel = (Telemetry.zeros(len(self.types), shape)
                        if telemetry else None)
                 if self.workload.n_queries == 0 and configs.size:
                     # 0/0 convention: an empty stream has no violations.
-                    return np.full(shape, np.nan, dtype=np.float64), tel
-                return np.zeros(shape, dtype=np.float64), tel
+                    rates = np.full(shape, np.nan, dtype=np.float64)
+                else:
+                    rates = np.zeros(shape, dtype=np.float64)
+                return _GridPending(None, n_w, n_p * n_b, shape, rates, tel)
             type_of_slot, active = self._slots_batch(configs)
             if states is not None:
                 if len(states) != n_w:
@@ -2063,19 +2095,30 @@ class PoolSimulator:
                 free0 = self._warm_free0_rows(
                     state, free_mat, active, float(arrivals[:, -1].max()),
                     "warm-start grid")
-        tel = None
         if telemetry:
             counts, tel = self._qos_counts_grid_tel(
                 arrivals, tables, type_of_slot, free0, configs, policy,
-                (n_w, n_p, n_b) if stacked else None)
-        else:
-            counts = self._qos_counts_grid(arrivals, tables, type_of_slot,
-                                           free0, configs, load_factors,
-                                           policy)
-        rates = counts.astype(np.float64) / self.workload.n_queries
-        if stacked:
-            rates = rates.reshape(n_w, n_p, n_b)
-        return rates, tel
+                shape if stacked else None)
+            rates = counts.astype(np.float64) / self.workload.n_queries
+            return _GridPending(None, n_w, n_p * n_b, shape,
+                                rates.reshape(shape), tel)
+        counts = self._qos_counts_grid(arrivals, tables, type_of_slot, free0,
+                                       configs, load_factors, policy)
+        return _GridPending(counts, n_w, n_p * n_b, shape)
+
+    def _qos_grid_fetch(self, pending: _GridPending,
+                        queued: int = 0) -> np.ndarray:
+        """The rates of a pending grid dispatch: waits for its counts
+        (span ``sim.wait``, lane ``grid``; ``queued`` is the number of
+        other dispatches its caller issued and has not fetched yet), keeps
+        their real block and divides by the stream's length."""
+        if pending.counts is None:
+            return pending.rates
+        counts = np.asarray(_fetch(pending.counts, "grid", self.request,
+                                   queued=queued))
+        rates = (counts[:pending.n_w, :pending.n_l].astype(np.float64)
+                 / self.workload.n_queries)
+        return rates.reshape(pending.shape)
 
     def qos_rate_grid(self, configs, load_factors,
                       service_tables=None) -> np.ndarray:
@@ -2085,7 +2128,7 @@ class PoolSimulator:
                         service_tables=service_tables).rates
 
     def _qos_counts_grid(self, arrivals, tables, type_of_slot, free0_rows,
-                         configs, load_factors, policy=None) -> np.ndarray:
+                         configs, load_factors, policy=None) -> jax.Array:
         """One fused (W, L) QoS-count sweep from per-config initial carries
         (``free0_rows``: (B, max_instances) float32, or (W, B, max_instances)
         for the per-row ``states=`` grid) — the shared dispatch behind the
@@ -2094,7 +2137,9 @@ class PoolSimulator:
         policy fold (L = P·B).  Every flavor — plain, stacked-table, routed,
         and both combined — shards across the host devices through
         ``_dispatch_grid_sharded`` when several are configured; the per-row
-        ``states=`` carries run the single-device states jits."""
+        ``states=`` carries run the single-device states jits.  Returns the
+        device counts, unfetched (the sharded path's hold pad rows or
+        lanes past the real (W, L) block)."""
         with tracing.span("sim.stage", self.request, lane="grid"):
             width = self._grid_slot_pad(configs.sum(axis=1))
             arr = np.asarray(arrivals, np.float32)                # (W, nq)
@@ -2163,7 +2208,7 @@ class PoolSimulator:
             return self._dispatch_grid_sharded(arr, tables, tos, free0,
                                                width, n_dev, factors,
                                                policy_ops)
-        return np.asarray(_fetch(counts, "grid", self.request))
+        return counts
 
     def _qos_counts_grid_tel(self, arrivals, tables, type_of_slot,
                              free0_rows, configs, policy,
@@ -2268,7 +2313,7 @@ class PoolSimulator:
         return out
 
     def _dispatch_grid_sharded(self, arr, tables, tos, free0, width, n_dev,
-                               factors, policy_ops=None) -> np.ndarray:
+                               factors, policy_ops=None) -> jax.Array:
         """One shard_mapped sweep across the lane mesh — every grid flavor
         (plain / stacked-table / routed / both).
 
@@ -2276,9 +2321,10 @@ class PoolSimulator:
         when it does not divide) unless the lane axis divides more cleanly —
         e.g. a single-level sweep over many configs or a wide policy fold.
         The shard_mapped executable takes global operands (no per-device
-        leading axis); pad rows are sliced off the result, and per-device
-        blocks run the same per-lane vmap bodies as the single-device jits,
-        so counts are bit-identical to them.
+        leading axis) and per-device blocks run the same per-lane vmap
+        bodies as the single-device jits, so counts are bit-identical to
+        them.  Returns the device counts, unfetched, pad rows or lanes
+        included: the fetch keeps the real (W, L) block.
         """
         n_w, n_b = len(arr), len(tos)
         with tracing.span("sim.stage", self.request, lane="grid"):
@@ -2318,8 +2364,7 @@ class PoolSimulator:
                 self._grid_arr_shards(arr, mode, n_dev, factors), svc,
                 jnp.asarray(tos), prio_r, jnp.asarray(free0), iota_r, qos_r,
                 *(jnp.asarray(x) for x in extra))
-        counts = np.asarray(_fetch(counts, "grid", self.request))
-        return counts[:, :n_b] if split_b else counts[:n_w]
+        return counts
 
 
 @dataclass(frozen=True)

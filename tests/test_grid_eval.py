@@ -8,9 +8,14 @@ Three equivalence contracts of the PR:
   (``pruning.apply_prune_rules``) stays bit-identical to the host-side
   ``PruneSet`` + sampled mirrors over whole recorded BO runs;
 * grid-driven ``rescale`` — the autoscaler-in-the-loop search lands on a
-  configuration that is genuinely feasible under the scaled load.
+  configuration that is genuinely feasible under the scaled load;
+* pipelined sweeps — an evaluator sweep of several dispatches, all issued
+  before the first is fetched, returns and memoizes exactly what scoring
+  each chunk through the blocking ``qos`` lane gives, on one device and
+  on four.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,7 +24,8 @@ from repro.core.search_space import SearchSpace
 from repro.serving.autoscaler import rescale
 from repro.serving.instance import (InstanceType, ModelProfile,
                                     service_time_table)
-from repro.serving.pool import PoolEvaluator
+from repro.serving.pool import PoolEvaluator, make_paper_setup
+from repro.serving.routing import RoutingPolicy
 from repro.serving.simulator import PoolSimulator, _qos_threshold_f32
 from repro.serving.workload import generate_workload
 
@@ -471,3 +477,135 @@ def test_grid_arr_shard_cache_is_lru_with_hit_refresh():
     assert hot in sim._grid_arrs                # survived thanks to the hit
     assert ("b", 2, (1.1,)) not in sim._grid_arrs   # the stalest went
     assert len(sim._grid_arrs) == 8
+
+
+# ---------------------------------------------- pipelined multi-dispatch sweep
+SWEEP_FLAVORS = ("cold", "warm", "routed")
+
+
+def _sweep_case(flavor, n_pools):
+    """A small candle evaluator, its sweep of ``n_pools`` pools x 3 loads
+    for ``flavor``, the blocking ``qos`` arguments that score one chunk the
+    same way, and the memo the sweep fills (as ``{(factor, pool): rate}``
+    dicts in write order)."""
+    ev, space, _ = make_paper_setup("candle", n_queries=150)
+    # Pools of 36 down to 6 instances: the first chunk fills all 40 slots,
+    # so its layouts and carries go to the device uncopied.
+    pools = space.enumerate()[::-211][:n_pools]
+    factors = (1.0, 1.25, 1.5)
+    if flavor == "cold":
+        def sweep():
+            return ev.grid(pools, factors)
+
+        def memo():
+            return [{(1.0, k): v for k, v in ev._cache.items()},
+                    ev._grid_cache]
+        kw = {}
+    elif flavor == "warm":
+        deployed = (2, 3, 4)
+        seg = ev.sim.segment_from(ev.sim.initial_state(), deployed)
+        state = seg.state_at(90).rebased(float(ev.workload.arrivals[89]))
+
+        def sweep():
+            return ev.grid_from(state, pools, factors, deployed=deployed)
+
+        def memo():
+            (cache,) = ev._warm_cache.values()
+            return [cache]
+        kw = {"state": state, "deployed": deployed}
+    else:
+        policy = RoutingPolicy.cost_aware([t.price for t in ev.types])
+
+        def sweep():
+            return ev.grid(pools, factors, policy=policy)
+
+        def memo():
+            cold, grid = ev._policy_caches[policy.key()]
+            return [{(1.0, k): v for k, v in cold.items()}, grid]
+        kw = {"policy": policy}
+    return ev, pools, factors, sweep, memo, kw
+
+
+def _check_pipelined_sweep(flavor, n_pools, chunk=4):
+    """The sweep, in chunks of ``chunk`` pools, against each chunk scored
+    through blocking ``sim.qos`` on a fresh evaluator: the returned grid,
+    every memo cell (and the order they were written in) and ``n_evals``
+    bit for bit.  No host array the sweep hands to the device is written
+    again before the sweep ends (on the CPU the device may read it in
+    place, after a later dispatch was staged)."""
+    ev, pools, factors, sweep, memo, kw = _sweep_case(flavor, n_pools)
+    ref_ev, _, _, _, _, _ = _sweep_case(flavor, n_pools)
+    blocks = []
+    for i in range(0, len(pools), chunk):
+        part = pools[i:i + chunk]
+        n = len(part)
+        width = 1 << (n - 1).bit_length()
+        padded = np.concatenate([part, np.repeat(part[:1], width - n,
+                                                 axis=0)])
+        blocks.append(ref_ev.sim.qos(padded, workloads=factors,
+                                     **kw).rates[:, :n])
+    want = np.concatenate(blocks, axis=1)
+    handed = []
+    asarray = jnp.asarray
+
+    def recording(x, *args, **kwargs):
+        if isinstance(x, np.ndarray):
+            handed.append((x, x.copy()))
+        return asarray(x, *args, **kwargs)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(PoolEvaluator, "_chunk", chunk)
+        mp.setattr(jnp, "asarray", recording)
+        got = sweep()
+    assert handed
+    for x, then in handed:
+        np.testing.assert_array_equal(x, then)
+    np.testing.assert_array_equal(got, want)
+    assert got.dtype == want.dtype
+    assert ev.n_evals == len(factors) * len(pools)
+    keys = [tuple(int(c) for c in p) for p in pools]
+    written = [((f, k), float(want[w, b]))
+               for i in range(0, len(keys), chunk)
+               for w, f in enumerate(factors)
+               for b, k in enumerate(keys[i:i + chunk], start=i)]
+    dicts = memo()
+    if len(dicts) == 2:
+        expect = [[kv for kv in written if kv[0][0] == 1.0],
+                  [kv for kv in written if kv[0][0] != 1.0]]
+    else:
+        expect = [written]
+    assert [list(d.items()) for d in dicts] == expect
+
+
+@pytest.mark.parametrize("n_pools", [10, 11])
+@pytest.mark.parametrize("flavor", SWEEP_FLAVORS)
+def test_pipelined_sweep_matches_blocking_chunks(flavor, n_pools):
+    """Three dispatches a sweep (the last 2 pools wide, or 3 padded to 4):
+    cold, warm and routed sweeps are bit-identical to blocking qos."""
+    _check_pipelined_sweep(flavor, n_pools)
+
+
+def test_pipelined_sweep_matches_blocking_chunks_on_four_devices():
+    """The same sweeps on four forced host devices, where each dispatch
+    runs the shard_map lane split (a 2-pool chunk pads a load level)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parent.parent
+    script = ("import sys\n"
+              f"sys.path.insert(0, {str(root / 'tests')!r})\n"
+              "import jax\n"
+              "assert jax.local_device_count() == 4\n"
+              "import test_grid_eval as t\n"
+              "for flavor in t.SWEEP_FLAVORS:\n"
+              "    for n_pools in (10, 11):\n"
+              "        t._check_pipelined_sweep(flavor, n_pools)\n"
+              "print('PIPELINED-OK')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="src",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=600,
+                          cwd=str(root))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "PIPELINED-OK" in proc.stdout

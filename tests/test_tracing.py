@@ -13,7 +13,9 @@ Contracts under test:
 * the ring keeps at most its capacity and counts what it pushed out;
 * the scenario engine's search clocks are ``scenario.search`` spans whose
   wall durations feed its ``TraceRecorder``;
-* no program span takes a name the benchmark's own spans use.
+* no program span takes a name the benchmark's own spans use;
+* a sweep of several grid dispatches stages them all before its first
+  wait, and each wait counts the dispatches still queued behind it.
 """
 
 import dataclasses
@@ -34,7 +36,7 @@ from repro.scenario import (PhaseSpec, ScenarioEngine, ScenarioSpec,
                             SimulatorPlane, TraceRecorder)
 from repro.serving import StreamingSimulator, make_paper_setup
 from repro.serving.instance import InstanceType, ModelProfile
-from repro.serving.pool import paper_spec
+from repro.serving.pool import PoolEvaluator, paper_spec
 from repro.serving.workload import generate_workload
 
 SRC = Path(__file__).resolve().parents[1] / "src" / "repro"
@@ -278,3 +280,30 @@ def test_sharded_grid_stages_before_its_one_wait(monkeypatch, tmp_path):
     (wait,) = _by_name(recs, "sim.wait", lane="grid")
     assert len(stages) == 3
     assert all(r.end_ns <= wait.start_ns for r in stages)
+
+
+def test_sweep_issues_every_dispatch_before_its_first_wait(monkeypatch,
+                                                           tmp_path):
+    """A sweep of four grid dispatches: every grid ``sim.stage`` ends
+    before the first grid ``sim.wait`` starts, and the waits' ``queued``
+    args read 3, 2, 1, 0."""
+    ev, space, _ = make_paper_setup("candle", n_queries=150)
+    monkeypatch.setattr(PoolEvaluator, "_chunk", 4)
+    tracing.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        ev.grid(space.enumerate()[::97][:15], (1.0, 1.25))
+    recs = tracing.records()
+    tracing.clear()
+    stages = _by_name(recs, "sim.stage", lane="grid")
+    waits = sorted(_by_name(recs, "sim.wait", lane="grid"),
+                   key=lambda r: r.start_ns)
+    assert len(waits) == 4 and len(stages) == 2 * len(waits)
+    assert max(r.end_ns for r in stages) <= waits[0].start_ns
+    assert [r.args["queued"] for r in waits] == [3, 2, 1, 0]
+    for r in stages + waits:
+        assert r.parent is None and r.request == ev.request
+    # Each wait is followed by its memo writes, before the next wait.
+    memos = _by_name(recs, "pool.memo")
+    for a, b in zip(waits, waits[1:]):
+        assert any(a.end_ns <= m.start_ns and m.end_ns <= b.start_ns
+                   for m in memos)
