@@ -43,6 +43,26 @@ class ArchConfig:
     top_k: int = 0
     d_expert: int = 0           # per-expert hidden dim (d_ff used if 0)
     moe_capacity_factor: float = 1.25
+    # DeepSeek-style MoE: shared experts (one SwiGLU of width
+    # n_shared_experts * d_expert, added once per token), top-k weights
+    # left unnormalised unless norm_topk_prob, scaled by routed_scaling
+    n_shared_experts: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling: float = 1.0
+    # leading dense SwiGLU layers (width d_ff) ahead of the MoE stack
+    first_dense_layers: int = 0
+    # experts held on this chip: > 0 selects the dropless expert-share layer
+    # (routes over all n_experts, computes experts 0..experts_held-1 for the
+    # tokens routed to them); 0 keeps the capacity-buffer layer
+    experts_held: int = 0
+
+    # YaRN rope scaling (yarn_factor 0 = plain rope)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (Mamba2 / SSD) options
     ssm_state: int = 0
@@ -115,6 +135,15 @@ class ArchConfig:
     def expert_ff(self) -> int:
         return self.d_expert if self.d_expert else self.d_ff
 
+    @property
+    def expert_share(self) -> bool:
+        """The dropless expert-share MoE layer (``experts_held`` > 0)."""
+        return self.is_moe and self.experts_held > 0
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_dense_layers
+
     def reduced(self) -> "ArchConfig":
         """Smoke-test configuration: same family/topology, tiny sizes."""
         updates = dict(
@@ -133,6 +162,9 @@ class ArchConfig:
             d_expert=32 if self.n_experts else 0,
             # dropless at smoke scale so decode ≡ forward exactly
             moe_capacity_factor=8.0,
+            experts_held=min(self.experts_held, 4) if self.experts_held else 0,
+            n_shared_experts=min(self.n_shared_experts, 1),
+            first_dense_layers=min(self.first_dense_layers, 1),
             q_lora_rank=24 if self.q_lora_rank else 0,
             kv_lora_rank=16 if self.kv_lora_rank else 0,
             qk_rope_dim=8 if self.qk_rope_dim else 0,
@@ -150,4 +182,6 @@ class ArchConfig:
         )
         if self.attn_every:
             updates["n_layers"] = 4
+        if self.first_dense_layers:
+            updates["n_layers"] = 3
         return dataclasses.replace(self, **updates)

@@ -3,6 +3,8 @@
 from __future__ import annotations
 
 from .base import ArchConfig
+from .deepseek_v2_lite import CONFIG as deepseek_v2_lite
+from .deepseek_v2_lite import ONE_CHIP as deepseek_v2_lite_chip
 from .internvl2_1b import CONFIG as internvl2_1b
 from .mamba2_130m import CONFIG as mamba2_130m
 from .minicpm3_4b import CONFIG as minicpm3_4b
@@ -18,7 +20,14 @@ ARCHS: dict[str, ArchConfig] = {
     c.name: c for c in [
         olmoe_1b_7b, mixtral_8x22b, qwen2_5_3b, minicpm3_4b, stablelm_3b,
         qwen2_7b, internvl2_1b, whisper_tiny, mamba2_130m, zamba2_2_7b,
+        deepseek_v2_lite,
     ]
+}
+
+# What one chip holds of an architecture too large for it, where a
+# deployment's share is stated (the live plane serves these at "full").
+CHIP_SHARES: dict[str, ArchConfig] = {
+    deepseek_v2_lite.name: deepseek_v2_lite_chip,
 }
 
 # (seq_len, global_batch, kind)

@@ -103,7 +103,7 @@ def build_lowered(arch: str, shape: str, multi_pod: bool,
             jitted = jax.jit(step,
                              in_shardings=(p_sh, cache_sh,
                                            batch_sh["tokens"]),
-                             out_shardings=(None, cache_sh),
+                             out_shardings=(None, cache_sh, None),
                              donate_argnums=(1,))
             lowered = jitted.lower(p_sds, cache_sds, batch_sds["tokens"])
     return lowered, mesh, cfg, (seq, gbatch, kind)

@@ -12,6 +12,8 @@ dry-run.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,11 +65,51 @@ def gelu_mlp(params, x):
 # --------------------------------------------------------------------------
 
 
-def rope_cos_sin(positions, dim, theta):
-    """cos/sin tables for rotary embedding.  positions (...,S) int."""
-    inv_freq = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+def rope_cos_sin(positions, dim, theta, cfg=None):
+    """cos/sin tables for rotary embedding.  positions (...,S) int.  A
+    ``cfg`` with ``yarn_factor`` set gives YaRN's frequencies and scale."""
+    if cfg is not None and cfg.yarn_factor:
+        inv_freq = yarn_inv_freq(dim, theta, cfg)
+        m = (yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    else:
+        inv_freq = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32)
+                                    / dim))
+        m = 1.0
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # (...,S,dim/2)
-    return jnp.cos(angles), jnp.sin(angles)
+    if m == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * m, jnp.sin(angles) * m
+
+
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention scale for a context stretched ``scale`` times."""
+    if scale <= 1 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, cfg) -> np.ndarray:
+    """YaRN inverse frequencies (DeepSeek-V2's ``DeepseekV2YarnRotary
+    Embedding``): the original frequencies below the correction range's
+    low end (high frequencies, kept), the interpolated ones (divided by
+    the factor) above its high end, a linear ramp between."""
+    base = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extra, inter = 1.0 / base, 1.0 / (cfg.yarn_factor * base)
+
+    def corr_dim(rotations):
+        return (dim * math.log(cfg.yarn_original_max_pos
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
 
 
 def apply_rope(x, cos, sin):
@@ -218,30 +260,49 @@ def gqa_attention(params, x, cfg, positions):
 
 
 def init_mla_params(key, cfg, dtype=jnp.float32):
+    """MLA weights; with ``q_lora_rank`` 0 the queries come straight from
+    the hidden state through ``wq`` (DeepSeek-V2-Lite), else through the
+    low-rank ``wdq`` -> ``q_norm`` -> ``wuq`` path."""
     d, h = cfg.d_model, cfg.n_heads
     qr, r = cfg.q_lora_rank, cfg.kv_lora_rank
     nd, rd, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     ks = jax.random.split(key, 7)
     s = d ** -0.5
-    return {
-        "wdq": (jax.random.normal(ks[0], (d, qr)) * s).astype(dtype),
-        "wuq": (jax.random.normal(ks[1], (qr, h * (nd + rd))) * qr ** -0.5).astype(dtype),
+    p = {
         "wdkv": (jax.random.normal(ks[2], (d, r)) * s).astype(dtype),
         "wkr": (jax.random.normal(ks[3], (d, rd)) * s).astype(dtype),
         "wuk": (jax.random.normal(ks[4], (r, h * nd)) * r ** -0.5).astype(dtype),
         "wuv": (jax.random.normal(ks[5], (r, h * vd)) * r ** -0.5).astype(dtype),
         "wo": (jax.random.normal(ks[6], (h * vd, d)) * (h * vd) ** -0.5).astype(dtype),
-        "q_norm": jnp.ones((qr,), dtype),
         "kv_norm": jnp.ones((r,), dtype),
     }
+    if qr:
+        p["wdq"] = (jax.random.normal(ks[0], (d, qr)) * s).astype(dtype)
+        p["wuq"] = (jax.random.normal(ks[1], (qr, h * (nd + rd)))
+                    * qr ** -0.5).astype(dtype)
+        p["q_norm"] = jnp.ones((qr,), dtype)
+    else:
+        p["wq"] = (jax.random.normal(ks[0], (d, h * (nd + rd)))
+                   * s).astype(dtype)
+    return p
+
+
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(nope + rope head dim), times YaRN's ``mscale_all_dim`` scale
+    squared where the config stretches its rope (DeepSeek-V2)."""
+    m = (yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+         if cfg.yarn_factor else 1.0)
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 * m * m
 
 
 def mla_latents(params, x, cfg, positions):
-    """Compute per-token latents: c_q (B,S,qr), c_kv (B,S,r), k_rope (B,S,rd)."""
-    c_q = rmsnorm(dense(x, params["wdq"]), params["q_norm"], cfg.norm_eps)
+    """Per-token latents: c_q (B,S,qr) (x itself with no query
+    compression), c_kv (B,S,r), k_rope (B,S,rd)."""
+    c_q = (rmsnorm(dense(x, params["wdq"]), params["q_norm"], cfg.norm_eps)
+           if cfg.q_lora_rank else x)
     c_kv = rmsnorm(dense(x, params["wdkv"]), params["kv_norm"], cfg.norm_eps)
     k_rope = dense(x, params["wkr"])
-    cos, sin = rope_cos_sin(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, cfg.qk_rope_dim, cfg.rope_theta, cfg)
     k_rope = apply_rope(k_rope[..., None, :], cos, sin)[..., 0, :]
     return c_q, c_kv, k_rope
 
@@ -250,15 +311,24 @@ def mla_queries(params, c_q, cfg, positions):
     """q_nope (B,S,H,nd), q_rope (B,S,H,rd)."""
     b, s, _ = c_q.shape
     h, nd, rd = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = dense(c_q, params["wuq"]).reshape(b, s, h, nd + rd)
+    w = params["wuq"] if cfg.q_lora_rank else params["wq"]
+    q = dense(c_q, w).reshape(b, s, h, nd + rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    cos, sin = rope_cos_sin(positions, rd, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, rd, cfg.rope_theta, cfg)
     q_rope = apply_rope(q_rope, cos, sin)
     return q_nope, q_rope
 
 
-def mla_attention(params, x, cfg, positions):
-    """Full-sequence MLA (materializes K/V from latents — train/prefill)."""
+def _q_block(b: int, h: int) -> int:
+    """Query block of the scanned attention: at most 2^17 query rows of
+    scores (batch x heads x block) live at once, and no more than
+    ``Q_BLOCK``."""
+    return int(max(128, min(Q_BLOCK, (1 << 17) // max(b * h, 1))))
+
+
+def mla_attention(params, x, cfg, positions, return_latents=False):
+    """Full-sequence MLA (materializes K/V from latents — train/prefill).
+    ``return_latents`` also returns the (c_kv, k_rope) a cache holds."""
     b, s, _ = x.shape
     h, nd, rd, vd = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     c_q, c_kv, k_rope = mla_latents(params, x, cfg, positions)
@@ -268,9 +338,10 @@ def mla_attention(params, x, cfg, positions):
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                                   (b, s, h, rd))], axis=-1)
-    out = attention_full(q, k, v, positions, positions, 0, (nd + rd) ** -0.5)
-    out = out.reshape(b, s, h * vd)
-    return dense(out, params["wo"])
+    out = attention_full(q, k, v, positions, positions, 0,
+                         mla_softmax_scale(cfg), q_block=_q_block(b, h))
+    out = dense(out.reshape(b, s, h * vd), params["wo"])
+    return (out, c_kv, k_rope) if return_latents else out
 
 
 def mla_decode_absorbed(params, x, cfg, cache_ckv, cache_krope, valid, pos):
@@ -288,8 +359,8 @@ def mla_decode_absorbed(params, x, cfg, cache_ckv, cache_krope, valid, pos):
     if valid.ndim == 1:
         valid = valid[None, :]
     b = x.shape[0]
-    h, nd, rd, vd, r = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
-                        cfg.v_head_dim, cfg.kv_lora_rank)
+    h, nd, vd, r = (cfg.n_heads, cfg.qk_nope_dim, cfg.v_head_dim,
+                    cfg.kv_lora_rank)
     c_q, c_kv_new, k_rope_new = mla_latents(params, x, cfg, pos)
     q_nope, q_rope = mla_queries(params, c_q, cfg, pos)       # (B,1,H,·)
     # absorb W_uk: q_lat (B,H,r)
@@ -297,7 +368,7 @@ def mla_decode_absorbed(params, x, cfg, cache_ckv, cache_krope, valid, pos):
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], wuk.astype(q_nope.dtype))
     scores = (jnp.einsum("bhr,btr->bht", q_lat, cache_ckv)
               + jnp.einsum("bhd,btd->bht", q_rope[:, 0], cache_krope))
-    scores = scores.astype(jnp.float32) * (nd + rd) ** -0.5
+    scores = scores.astype(jnp.float32) * mla_softmax_scale(cfg)
     scores = jnp.where(valid[:, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
     lat = jnp.einsum("bht,btr->bhr", probs, cache_ckv)        # attended latent
@@ -313,17 +384,104 @@ def mla_decode_absorbed(params, x, cfg, cache_ckv, cache_krope, valid, pos):
 
 
 def init_moe_params(key, cfg, dtype=jnp.float32):
+    """Router over all ``n_experts`` (float32), the weights of the experts
+    held here (``experts_held``, else all) and of the shared experts."""
     d, e, f = cfg.d_model, cfg.n_experts, cfg.expert_ff
+    held = cfg.experts_held or e
     ks = jax.random.split(key, 4)
     s = d ** -0.5
-    return {
+    p = {
         "router": (jax.random.normal(ks[0], (d, e)) * s).astype(jnp.float32),
         "experts": {
-            "w1": (jax.random.normal(ks[1], (e, d, f)) * s).astype(dtype),
-            "w3": (jax.random.normal(ks[2], (e, d, f)) * s).astype(dtype),
-            "w2": (jax.random.normal(ks[3], (e, f, d)) * f ** -0.5).astype(dtype),
+            "w1": (jax.random.normal(ks[1], (held, d, f)) * s).astype(dtype),
+            "w3": (jax.random.normal(ks[2], (held, d, f)) * s).astype(dtype),
+            "w2": (jax.random.normal(ks[3], (held, f, d)) * f ** -0.5).astype(dtype),
         },
     }
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        ka, kb, kc = jax.random.split(jax.random.fold_in(key, 1), 3)
+        p["shared"] = {
+            "w1": (jax.random.normal(ka, (d, fs)) * s).astype(dtype),
+            "w3": (jax.random.normal(kb, (d, fs)) * s).astype(dtype),
+            "w2": (jax.random.normal(kc, (fs, d)) * fs ** -0.5).astype(dtype),
+        }
+    return p
+
+
+# Tokens an expert-share layer routes in one pass; longer inputs are
+# scanned in chunks of this many, which bounds the (tokens x top_k) rows
+# of the gathered activations.
+MOE_CHUNK = 8192
+
+
+def moe_expert_share(params, x, cfg, token_mask=None, first_expert=0):
+    """Dropless MoE over the experts held here (DeepSeek-V2 semantics).
+
+    Routes every token over all ``n_experts`` (float32 softmax, top-k,
+    renormalised only if ``norm_topk_prob``, times ``routed_scaling``),
+    then computes only the held experts ``first_expert ..
+    first_expert + held - 1`` for the tokens routed to them: the (token,
+    expert) pairs are sorted by expert and run through a grouped matmul
+    (``lax.ragged_dot``) over the held experts, no capacity buffer and no
+    token dropped.  The shared experts are added once per token.  Tokens
+    where ``token_mask`` (B,S) is False (padding) get no routed part.
+
+    x (B,S,D) -> (out (B,S,D), counts (held,) int32: the tokens each held
+    expert received, the layer's program counter).
+    """
+    b, s, d = x.shape
+    t = b * s
+    xt = x.reshape(t, d)
+    mask = None if token_mask is None else token_mask.reshape(t)
+    if t > MOE_CHUNK and t % MOE_CHUNK == 0:
+        n = t // MOE_CHUNK
+        xs = xt.reshape(n, MOE_CHUNK, d)
+        ms = (jnp.ones((n, MOE_CHUNK), bool) if mask is None
+              else mask.reshape(n, MOE_CHUNK))
+        routed, counts = jax.lax.map(
+            lambda a: _routed_share(params, a[0], a[1], cfg, first_expert),
+            (xs, ms))
+        routed, counts = routed.reshape(t, d), counts.sum(axis=0)
+    else:
+        routed, counts = _routed_share(params, xt, mask, cfg, first_expert)
+    if "shared" in params:
+        routed = routed + swiglu_mlp(params["shared"], xt)
+    return routed.reshape(b, s, d), counts
+
+
+def _routed_share(params, xt, mask, cfg, first_expert):
+    """The held experts' part for tokens xt (T,D): (out (T,D), counts)."""
+    t, d = xt.shape
+    k = cfg.top_k
+    ew = params["experts"]
+    held = ew["w1"].shape[0]
+    probs = jax.nn.softmax(dense(xt.astype(jnp.float32), params["router"]),
+                           axis=-1)
+    topk_p, topk_e = jax.lax.top_k(probs, k)                     # (T,k)
+    if cfg.norm_topk_prob:
+        topk_p = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-20)
+    topk_p = topk_p * cfg.routed_scaling
+    local = topk_e - first_expert
+    here = (local >= 0) & (local < held)
+    if mask is not None:
+        here &= mask[:, None]
+    # (token, expert) pairs sorted by held expert; pairs for experts held
+    # elsewhere sort last under the key ``held`` and fall outside every
+    # group, so the grouped matmul leaves them zero
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    tok = order // k
+    counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    xs = xt[tok]
+    gate = jax.nn.silu(jax.lax.ragged_dot(xs, ew["w1"], counts))
+    up = jax.lax.ragged_dot(xs, ew["w3"], counts)
+    y = jax.lax.ragged_dot(gate * up, ew["w2"], counts)
+    wts = topk_p.reshape(-1)[order]
+    y = jnp.where((key[order] < held)[:, None],
+                  y * wts[:, None].astype(y.dtype), 0).astype(xt.dtype)
+    out = jnp.zeros((t, d), xt.dtype).at[tok].add(y)
+    return out, counts
 
 
 def moe_layer_local(params, x, cfg, capacity_factor: float | None = None):

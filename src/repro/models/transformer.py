@@ -7,6 +7,7 @@ Every family exposes the same functional API (``get_model(cfg) -> ModelApi``):
     loss(params, tokens, labels, extra)      -> scalar
     init_cache(batch, max_len, dtype)        -> cache pytree
     prefill(params, tokens, max_len, extra)  -> (cache, last_logits)
+                                                (decoder LMs: also lengths)
     decode_step(params, cache, tokens)       -> (logits, cache)
 
 Layer stacks are scanned over stacked parameters (HLO stays small for the
@@ -21,6 +22,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import ArchConfig
 from ..launch.sharding import constrain
@@ -30,7 +32,7 @@ from .layers import (attention_core, attention_full, dense, gelu_mlp,
                      gqa_attention, gqa_project_qkv, init_gqa_params,
                      init_mla_params, init_moe_params, layernorm,
                      mla_attention, mla_decode_absorbed, mla_latents,
-                     moe_layer, rmsnorm, swiglu_mlp)
+                     moe_expert_share, moe_layer, rmsnorm, swiglu_mlp)
 from .ssm import init_ssm_params, ssm_decode_step, ssm_forward
 
 
@@ -80,16 +82,19 @@ def _lm_loss(forward):
 
 
 def _ring_scatter(x, positions, window):
-    """x (B,S,...) keyed by absolute positions (S,) → ring (B,W,...), pos (W,)."""
-    b, s = x.shape[:2]
-    if s >= window:
-        xs, pos = x[:, s - window:], positions[s - window:]
-    else:
-        xs, pos = x, positions
-    slots = pos % window
-    ring = jnp.zeros((b, window) + x.shape[2:], x.dtype).at[:, slots].set(xs)
-    pos_table = jnp.full((window,), -1, jnp.int32).at[slots].set(pos)
-    return ring, pos_table
+    """x (B,S,...) keyed by absolute positions (S,) = 0..S-1 → ring
+    (B,W,...), pos (W,).  Position p lands in slot p mod W; built by
+    padding or rolling, with no scatter (the TPU compiler rejects this
+    scatter)."""
+    s = x.shape[1]
+    if s < window:
+        pad = [(0, 0), (0, window - s)] + [(0, 0)] * (x.ndim - 2)
+        return (jnp.pad(x, pad),
+                jnp.pad(positions.astype(jnp.int32), (0, window - s),
+                        constant_values=-1))
+    shift = (s - window) % window
+    return (jnp.roll(x[:, s - window:], shift, axis=1),
+            jnp.roll(positions[s - window:].astype(jnp.int32), shift))
 
 
 def _maybe_remat(fn, cfg):
@@ -101,7 +106,11 @@ def _maybe_remat(fn, cfg):
 # --------------------------------------------------------------------------
 
 
-def _init_decoder_layer(cfg, dtype):
+def _init_decoder_layer(cfg, dtype, moe=None):
+    """Init of one decoder layer; ``moe`` False gives a dense SwiGLU layer
+    of width ``d_ff`` (the leading dense layers of a MoE model)."""
+    moe = cfg.is_moe if moe is None else moe
+
     def init(key):
         k1, k2 = jax.random.split(key)
         d = cfg.d_model
@@ -111,7 +120,7 @@ def _init_decoder_layer(cfg, dtype):
             p["attn"] = init_mla_params(k1, cfg, dtype)
         else:
             p["attn"] = init_gqa_params(k1, cfg, dtype)
-        if cfg.is_moe:
+        if moe:
             p["moe"] = init_moe_params(k2, cfg, dtype)
         else:
             k2a, k2b, k2c = jax.random.split(k2, 3)
@@ -126,20 +135,49 @@ def _init_decoder_layer(cfg, dtype):
     return init
 
 
-def _decoder_block(cfg, layer_p, h, positions):
+def _stacks(cfg):
+    """(params key, cache key, MoE?) of each layer stack in order: the
+    leading dense layers (their cache under ``"lead"``) ahead of the main
+    stack (its cache at the top level)."""
+    main = ("layers", None, cfg.is_moe)
+    return ([("dense_layers", "lead", False), main] if cfg.first_dense_layers
+            else [main])
+
+
+def _cache_keys(cfg):
+    if cfg.attention == "mla":
+        return ("ckv", "krope")
+    if cfg.kv_quant_int8:
+        return ("k", "v", "k_scale", "v_scale")
+    return ("k", "v")
+
+
+_NO_COUNTS = np.zeros((0,), np.int32)
+
+
+def _ffn(cfg, layer_p, hn, moe, mask=None):
+    """Feed-forward half of a block: (out, aux loss, held-expert token
+    counts; empty unless the layer is an expert share)."""
+    if not moe:
+        return swiglu_mlp(layer_p["mlp"], hn), jnp.zeros((), jnp.float32), \
+            _NO_COUNTS
+    if cfg.expert_share:
+        out, counts = moe_expert_share(layer_p["moe"], hn, cfg, mask)
+        return out, jnp.zeros((), jnp.float32), counts
+    out, aux = moe_layer(layer_p["moe"], hn, cfg)
+    return out, aux, _NO_COUNTS
+
+
+def _decoder_block(cfg, layer_p, h, positions, moe=None):
+    moe = cfg.is_moe if moe is None else moe
     hn = rmsnorm(h, layer_p["attn_norm"], cfg.norm_eps)
     if cfg.attention == "mla":
         h = h + mla_attention(layer_p["attn"], hn, cfg, positions)
     else:
         h = h + gqa_attention(layer_p["attn"], hn, cfg, positions)
     hn = rmsnorm(h, layer_p["mlp_norm"], cfg.norm_eps)
-    if cfg.is_moe:
-        mo, aux = moe_layer(layer_p["moe"], hn, cfg)
-        h = h + mo
-    else:
-        h = h + swiglu_mlp(layer_p["mlp"], hn)
-        aux = jnp.zeros((), jnp.float32)
-    return constrain(h, "batch", None, None), aux
+    out, aux, _ = _ffn(cfg, layer_p, hn, moe)
+    return constrain(h + out, "batch", None, None), aux
 
 
 def _attn_decode_gqa(cfg, attn_p, hn, k_l, v_l, slot, t, valid):
@@ -177,14 +215,73 @@ def _attn_decode_gqa_q8(cfg, attn_p, hn, k_l, v_l, ks_l, vs_l, slot, t,
     return dense(out, attn_p["wo"]), k_l, v_l, ks_l, vs_l
 
 
+def _attn_prefill(cfg, attn_p, hn, positions, w):
+    """Full-sequence attention of one layer and what its cache layer
+    holds: (out, cache arrays in ``_cache_keys`` order, pos table)."""
+    s = hn.shape[1]
+    if cfg.attention == "mla":
+        out, c_kv, k_rope = mla_attention(attn_p, hn, cfg, positions,
+                                          return_latents=True)
+        ckv_ring, pos_table = _ring_scatter(c_kv, positions, w)
+        kr_ring, _ = _ring_scatter(k_rope, positions, w)
+        return out, (ckv_ring, kr_ring), pos_table
+    q, k, v = gqa_project_qkv(attn_p, hn, cfg, positions)
+    out = attention_full(q, k, v, positions, positions, cfg.sliding_window,
+                         cfg.d_head ** -0.5)
+    out = dense(out.reshape(hn.shape[0], s, cfg.n_heads * cfg.d_head),
+                attn_p["wo"])
+    k_ring, pos_table = _ring_scatter(k, positions, w)
+    v_ring, _ = _ring_scatter(v, positions, w)
+    if cfg.kv_quant_int8:
+        kq, ksc = quantize_kv(k_ring)
+        vq, vsc = quantize_kv(v_ring)
+        return out, (kq, vq, ksc, vsc), pos_table
+    return out, (k_ring, v_ring), pos_table
+
+
+def _attn_decode(cfg, attn_p, hn, layers, slot, t, valid):
+    """Single-token attention of one layer against its cache layers (in
+    ``_cache_keys`` order): (out, updated cache layers)."""
+    if cfg.attention == "mla":
+        ckv_l, kr_l = layers
+        pos_arr = jnp.full((hn.shape[0], 1), t, jnp.int32)
+        # write the new token's latents, then attend (absorbed form)
+        _, ckv_new, kr_new = mla_latents(attn_p, hn, cfg, pos_arr)
+        ckv_l = ckv_l.at[:, slot].set(ckv_new[:, 0].astype(ckv_l.dtype))
+        kr_l = kr_l.at[:, slot].set(kr_new[:, 0].astype(kr_l.dtype))
+        out, _, _ = mla_decode_absorbed(attn_p, hn, cfg, ckv_l, kr_l, valid,
+                                        pos_arr)
+        return out, (ckv_l, kr_l)
+    if cfg.kv_quant_int8:
+        out, *new = _attn_decode_gqa_q8(cfg, attn_p, hn, *layers, slot, t,
+                                        valid)
+        return out, tuple(new)
+    out, k_l, v_l = _attn_decode_gqa(cfg, attn_p, hn, *layers, slot, t, valid)
+    return out, (k_l, v_l)
+
+
 def make_decoder_lm(cfg: ArchConfig) -> ModelApi:
+    """Decoder LM.  ``prefill`` takes optional per-row ``lengths`` (B,):
+    row b's prompt is its first ``lengths[b]`` tokens (0 marks a padding
+    row), every real row has the same length, and decoding continues from
+    there; the rest of the row is padding that no cache slot keeps and no
+    expert share computes.  An expert-share model's cache carries
+    ``held_tokens`` (MoE layers x held experts): the tokens each held
+    expert received in the step that made it."""
     is_vlm = cfg.family == "vlm"
+    stacks = _stacks(cfg)
+    keys = _cache_keys(cfg)
 
     def init_params(key, dtype=jnp.float32):
         k1, k2 = jax.random.split(key)
         params = _init_embed(k1, cfg, dtype)
+        n_lead = cfg.first_dense_layers
         params["layers"] = _stacked(_init_decoder_layer(cfg, dtype), k2,
-                                    cfg.n_layers)
+                                    cfg.n_layers - n_lead)
+        if n_lead:
+            params["dense_layers"] = _stacked(
+                _init_decoder_layer(cfg, dtype, moe=False),
+                jax.random.fold_in(k2, 1), n_lead)
         return params
 
     def forward(params, tokens, extra=None):
@@ -194,151 +291,99 @@ def make_decoder_lm(cfg: ArchConfig) -> ModelApi:
         h = constrain(h, "batch", None, None)
         s = h.shape[1]
         positions = jnp.arange(s, dtype=jnp.int32)
+        aux = jnp.zeros((), jnp.float32)
+        for name, _, moe in stacks:
+            def block(h, layer_p, moe=moe):
+                return _decoder_block(cfg, layer_p, h, positions, moe)
 
-        def block(h, layer_p):
-            return _decoder_block(cfg, layer_p, h, positions)
-
-        h, auxs = jax.lax.scan(_maybe_remat(block, cfg), h, params["layers"])
-        return _logits(params, h, cfg), auxs.sum()
+            h, auxs = jax.lax.scan(_maybe_remat(block, cfg), h, params[name])
+            aux = aux + auxs.sum()
+        return _logits(params, h, cfg), aux
 
     def init_cache(batch, max_len, dtype=jnp.bfloat16):
         w = cache_window(cfg, max_len)
-        if cfg.attention == "mla":
-            return init_mla_cache(cfg, cfg.n_layers, batch, w, dtype)
-        return init_kv_cache(cfg, cfg.n_layers, batch, w, dtype)
 
-    def prefill(params, tokens, max_len, extra=None):
+        def layers(n):
+            if cfg.attention == "mla":
+                return init_mla_cache(cfg, n, batch, w, dtype)
+            return init_kv_cache(cfg, n, batch, w, dtype)
+
+        cache = layers(cfg.n_layers - cfg.first_dense_layers)
+        if cfg.first_dense_layers:
+            lead = layers(cfg.first_dense_layers)
+            cache["lead"] = {k: lead[k] for k in keys}
+        return cache
+
+    def prefill(params, tokens, max_len, extra=None, lengths=None):
         h = params["embed"][tokens]
         if is_vlm and extra is not None:
             h = jnp.concatenate([extra.astype(h.dtype), h], axis=1)
         h = constrain(h, "batch", None, None)
-        s = h.shape[1]
+        b, s = h.shape[:2]
         w = cache_window(cfg, max_len)
         positions = jnp.arange(s, dtype=jnp.int32)
-
-        if cfg.attention == "mla":
-            def block(h, layer_p):
+        mask = None if lengths is None else positions[None] < lengths[:, None]
+        cache = {}
+        for name, where, moe in stacks:
+            def block(h, layer_p, moe=moe):
                 hn = rmsnorm(h, layer_p["attn_norm"], cfg.norm_eps)
-                h = h + mla_attention(layer_p["attn"], hn, cfg, positions)
-                _, c_kv, k_rope = mla_latents(layer_p["attn"], hn, cfg,
-                                              positions)
-                ckv_ring, pos_table = _ring_scatter(c_kv, positions, w)
-                kr_ring, _ = _ring_scatter(k_rope, positions, w)
+                out, arrays, pos_table = _attn_prefill(cfg, layer_p["attn"],
+                                                       hn, positions, w)
+                h = h + out
                 hn = rmsnorm(h, layer_p["mlp_norm"], cfg.norm_eps)
-                h = h + swiglu_mlp(layer_p["mlp"], hn)
-                return h, (ckv_ring, kr_ring, pos_table)
+                out, _, counts = _ffn(cfg, layer_p, hn, moe, mask)
+                return h + out, (arrays, pos_table, counts)
 
-            h, (ckv, krope, pos_tables) = jax.lax.scan(
-                _maybe_remat(block, cfg), h, params["layers"])
-            cache = {"ckv": ckv, "krope": krope, "pos": pos_tables[0],
-                     "t": jnp.asarray(s, jnp.int32)}
-        else:
-            def block(h, layer_p):
-                hn = rmsnorm(h, layer_p["attn_norm"], cfg.norm_eps)
-                q, k, v = gqa_project_qkv(layer_p["attn"], hn, cfg, positions)
-                out = attention_full(q, k, v, positions, positions,
-                                     cfg.sliding_window, cfg.d_head ** -0.5)
-                out = out.reshape(h.shape[0], s, cfg.n_heads * cfg.d_head)
-                h = h + dense(out, layer_p["attn"]["wo"])
-                hn = rmsnorm(h, layer_p["mlp_norm"], cfg.norm_eps)
-                if cfg.is_moe:
-                    mo, _ = moe_layer(layer_p["moe"], hn, cfg)
-                    h = h + mo
-                else:
-                    h = h + swiglu_mlp(layer_p["mlp"], hn)
-                k_ring, pos_table = _ring_scatter(k, positions, w)
-                v_ring, _ = _ring_scatter(v, positions, w)
-                if cfg.kv_quant_int8:
-                    kq, ksc = quantize_kv(k_ring)
-                    vq, vsc = quantize_kv(v_ring)
-                    return h, (kq, vq, ksc, vsc, pos_table)
-                return h, (k_ring, v_ring, pos_table)
-
-            if cfg.kv_quant_int8:
-                h, (ks, vs, kscale, vscale, pos_tables) = jax.lax.scan(
-                    _maybe_remat(block, cfg), h, params["layers"])
-                cache = {"k": ks, "v": vs, "k_scale": kscale,
-                         "v_scale": vscale, "pos": pos_tables[0],
-                         "t": jnp.asarray(s, jnp.int32)}
+            h, (arrays, pos_tables, counts) = jax.lax.scan(
+                _maybe_remat(block, cfg), h, params[name])
+            part = dict(zip(keys, arrays))
+            if where is None:
+                cache.update(part, pos=pos_tables[0])
+                if cfg.expert_share:
+                    cache["held_tokens"] = counts
             else:
-                h, (ks, vs, pos_tables) = jax.lax.scan(
-                    _maybe_remat(block, cfg), h, params["layers"])
-                cache = {"k": ks, "v": vs, "pos": pos_tables[0],
-                         "t": jnp.asarray(s, jnp.int32)}
-        return cache, _logits(params, h[:, -1:], cfg)
+                cache[where] = part
+        if lengths is None:
+            cache["t"] = jnp.asarray(s, jnp.int32)
+            return cache, _logits(params, h[:, -1:], cfg)
+        t = jnp.max(lengths).astype(jnp.int32)
+        cache["pos"] = jnp.where(cache["pos"] < t, cache["pos"], -1)
+        cache["t"] = t
+        cache["rows"] = lengths > 0
+        last = jax.lax.dynamic_slice_in_dim(h, t - 1, 1, axis=1)
+        return cache, _logits(params, last, cfg)
 
     def decode_step(params, cache, tokens):
         t = cache["t"]
         h = constrain(params["embed"][tokens], "batch", None, None)
-        if cfg.attention == "mla":
-            w = cache["ckv"].shape[2]
-        else:
-            w = cache["k"].shape[2]
+        w = cache[keys[0]].shape[2]
         slot = jnp.mod(t, w)
         pos_table = cache["pos"].at[slot].set(t)
         valid = pos_table >= 0
+        rows = cache.get("rows")
+        mask = None if rows is None else rows[:, None]
+        new_cache = dict(cache, pos=pos_table, t=t + 1)
+        for name, where, moe in stacks:
+            part = cache if where is None else cache[where]
 
-        if cfg.attention == "mla":
-            def block(h, xs):
-                layer_p, ckv_l, kr_l = xs
+            def block(h, xs, moe=moe):
+                layer_p, layers = xs
                 hn = rmsnorm(h, layer_p["attn_norm"], cfg.norm_eps)
-                b = hn.shape[0]
-                pos_arr = jnp.full((b, 1), t, jnp.int32)
-                # write the new token's latents, then attend (absorbed form)
-                _, ckv_new, kr_new = mla_latents(layer_p["attn"], hn, cfg,
-                                                 pos_arr)
-                ckv_l = ckv_l.at[:, slot].set(ckv_new[:, 0].astype(ckv_l.dtype))
-                kr_l = kr_l.at[:, slot].set(kr_new[:, 0].astype(kr_l.dtype))
-                out, _, _ = mla_decode_absorbed(layer_p["attn"], hn, cfg,
-                                                ckv_l, kr_l, valid, pos_arr)
+                out, layers = _attn_decode(cfg, layer_p["attn"], hn, layers,
+                                           slot, t, valid)
                 h = h + out
                 hn = rmsnorm(h, layer_p["mlp_norm"], cfg.norm_eps)
-                h = h + swiglu_mlp(layer_p["mlp"], hn)
-                return h, (ckv_l, kr_l)
+                out, _, counts = _ffn(cfg, layer_p, hn, moe, mask)
+                return h + out, (layers, counts)
 
-            h, (ckv, krope) = jax.lax.scan(
-                block, h, (params["layers"], cache["ckv"], cache["krope"]))
-            new_cache = {"ckv": ckv, "krope": krope, "pos": pos_table,
-                         "t": t + 1}
-        elif cfg.kv_quant_int8:
-            def block(h, xs):
-                layer_p, k_l, v_l, ks_l, vs_l = xs
-                hn = rmsnorm(h, layer_p["attn_norm"], cfg.norm_eps)
-                out, k_l, v_l, ks_l, vs_l = _attn_decode_gqa_q8(
-                    cfg, layer_p["attn"], hn, k_l, v_l, ks_l, vs_l, slot, t,
-                    valid)
-                h = h + out
-                hn = rmsnorm(h, layer_p["mlp_norm"], cfg.norm_eps)
-                if cfg.is_moe:
-                    mo, _ = moe_layer(layer_p["moe"], hn, cfg)
-                    h = h + mo
-                else:
-                    h = h + swiglu_mlp(layer_p["mlp"], hn)
-                return h, (k_l, v_l, ks_l, vs_l)
-
-            h, (ks, vs, kscale, vscale) = jax.lax.scan(
-                block, h, (params["layers"], cache["k"], cache["v"],
-                           cache["k_scale"], cache["v_scale"]))
-            new_cache = {"k": ks, "v": vs, "k_scale": kscale,
-                         "v_scale": vscale, "pos": pos_table, "t": t + 1}
-        else:
-            def block(h, xs):
-                layer_p, k_l, v_l = xs
-                hn = rmsnorm(h, layer_p["attn_norm"], cfg.norm_eps)
-                out, k_l, v_l = _attn_decode_gqa(cfg, layer_p["attn"], hn,
-                                                 k_l, v_l, slot, t, valid)
-                h = h + out
-                hn = rmsnorm(h, layer_p["mlp_norm"], cfg.norm_eps)
-                if cfg.is_moe:
-                    mo, _ = moe_layer(layer_p["moe"], hn, cfg)
-                    h = h + mo
-                else:
-                    h = h + swiglu_mlp(layer_p["mlp"], hn)
-                return h, (k_l, v_l)
-
-            h, (ks, vs) = jax.lax.scan(
-                block, h, (params["layers"], cache["k"], cache["v"]))
-            new_cache = {"k": ks, "v": vs, "pos": pos_table, "t": t + 1}
+            h, (layers, counts) = jax.lax.scan(
+                block, h, (params[name], tuple(part[k] for k in keys)))
+            if where is None:
+                new_cache.update(zip(keys, layers))
+                if cfg.expert_share:
+                    new_cache["held_tokens"] = counts
+            else:
+                new_cache[where] = dict(zip(keys, layers))
         return _logits(params, h, cfg), new_cache
 
     return ModelApi(cfg, init_params, forward, _lm_loss(forward), init_cache,

@@ -68,6 +68,10 @@ class Workload:
     # (arrivals, batches, scaling) is bucket-agnostic.
     bucket_of: np.ndarray | None = None    # (n,) int bucket index per query
     buckets: tuple[RequestBucket, ...] | None = None
+    # LLM queries (None = not an LLM stream): prompt tokens and tokens to
+    # generate per query, shared by the query's requests
+    prompt_lens: np.ndarray | None = None
+    output_lens: np.ndarray | None = None
 
     @property
     def n_queries(self) -> int:
@@ -76,10 +80,8 @@ class Workload:
     def scaled(self, load_factor: float) -> "Workload":
         """Same query sequence under a different load level (paper §5.5:
         'the load becomes 1.5 times heavier' compresses inter-arrivals)."""
-        return Workload(arrivals=self.arrivals / load_factor,
-                        batches=self.batches,
-                        rate_qps=self.rate_qps * load_factor,
-                        bucket_of=self.bucket_of, buckets=self.buckets)
+        return replace(self, arrivals=self.arrivals / load_factor,
+                       rate_qps=self.rate_qps * load_factor)
 
 
 def lognormal_batches(key, n: int, median: float = 24.0, sigma: float = 0.8,
@@ -389,3 +391,61 @@ def generate_workload(seed: int, n_queries: int, rate_qps: float,
                         mean_batch=mean_batch, std_batch=std_batch,
                         max_batch=max_batch)
     return spec.realize(n_queries)
+
+
+# Fold-in tags of the two length streams of an LLM workload, drawn from
+# the spec seed's key as the bucket stream is (``_BUCKET_STREAM_TAG``): the
+# arrival and batch bits stay untouched.
+_PROMPT_STREAM_TAG = 0x9A0A17
+_OUTPUT_STREAM_TAG = 0x9A0A18
+
+
+@dataclass(frozen=True)
+class LengthLaw:
+    """Lognormal token lengths: ``round(exp(log(median) + sigma z))``
+    clipped to [lo, hi]."""
+
+    median: float
+    sigma: float
+    lo: int
+    hi: int
+
+
+@partial(jax.jit, static_argnames=("chunk",))
+def _length_chunk(key, c, log_median, sigma, lo, hi, *, chunk: int):
+    """Lengths of the queries of chunk ``c`` of a length stream."""
+    z = jax.random.normal(jax.random.fold_in(key, c), (chunk,),
+                          dtype=jnp.float32)
+    raw = jnp.exp(log_median + sigma * z)
+    return jnp.clip(jnp.round(raw), lo, hi).astype(jnp.int32)
+
+
+@dataclass(frozen=True)
+class LLMWorkloadSpec:
+    """A :class:`WorkloadSpec` whose queries are LLM requests: ``batches``
+    counts a query's requests, and each query draws a prompt length and an
+    output length from its own ``fold_in``-derived stream, chunk for
+    chunk.  ``realize`` is bit-identical to ``base.realize`` on arrivals
+    and batches."""
+
+    base: WorkloadSpec
+    prompt: LengthLaw
+    output: LengthLaw
+
+    def _lengths(self, tag: int, law: LengthLaw, n: int) -> np.ndarray:
+        key = jax.random.fold_in(jax.random.PRNGKey(self.base.seed), tag)
+        chunk = self.base.chunk
+        parts = [np.asarray(_length_chunk(
+            key, np.int64(c), jnp.float32(np.log(law.median)),
+            jnp.float32(law.sigma), jnp.float32(law.lo), jnp.float32(law.hi),
+            chunk=chunk)) for c in range(math.ceil(n / chunk))]
+        return (np.concatenate(parts)[:n].astype(np.int64) if parts
+                else np.zeros(0, np.int64))
+
+    def realize(self, n_queries: int) -> Workload:
+        wl = self.base.realize(n_queries)
+        return replace(
+            wl, prompt_lens=self._lengths(_PROMPT_STREAM_TAG, self.prompt,
+                                          n_queries),
+            output_lens=self._lengths(_OUTPUT_STREAM_TAG, self.output,
+                                      n_queries))

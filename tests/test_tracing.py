@@ -49,7 +49,8 @@ CALL_SPANS = {"ribbon.ask", "ribbon.select", "ribbon.tell", "pool.eval",
 # host events by name, so a program span of one of these names would move
 # its attribution of idle gaps.
 RESERVED = {"decision", "stream.realize", "ask", "oracle", "tell", "sweep",
-            "sweep.dispatch", "stream", "stream.chunk", "trace.window"}
+            "sweep.dispatch", "stream", "stream.chunk", "trace.window",
+            "verify"}
 
 
 def _calls():
