@@ -19,9 +19,16 @@ or config): one parameter copy for every cell type, and per query a
 prefill then greedy decoding through the cache (``serving/llm.py``); its
 workload carries each query's prompt and output lengths.
 
+A paper model's query is served the input batch of its (preset, power-of-
+two bucket): ``make_random_batch``'s draw, which is the same on every call,
+so the engine draws each one once (in ``warmup``, or on the bucket's first
+query) and holds it.
+
 Program spans (``repro.tracing``): ``live.serve`` covers one query from
-its dispatch to its record; ``live.execute`` a paper model's measured
-execution; an LLM query's are ``live.prefill`` and ``live.decode``.
+its dispatch to its record, and for a paper model carries ``drawn`` (1 if
+that query drew its batch, 0 if it was held); ``live.execute`` a paper
+model's measured execution; an LLM query's are ``live.prefill`` and
+``live.decode``.
 """
 
 from __future__ import annotations
@@ -152,6 +159,8 @@ class ClusterEngine:
         self.records: list[QueryRecord] = []
         # Outputs of the queries ``serve`` was asked to keep, by index.
         self.kept: dict[int, object] = {}
+        # A paper model's input batch per (preset, bucket), drawn once.
+        self._batches: dict[tuple[str, int], dict] = {}
 
     def load_params(self, params) -> None:
         """Serve every cell type with ``params``, a checkpoint in the
@@ -176,11 +185,22 @@ class ClusterEngine:
         b = 1
         while b <= max_batch:
             for ct in self.cell_types:
-                batch = make_random_batch(self.model_name, ct.preset, b)
+                batch, _ = self._input(ct.preset, b)
                 out = self._apply[ct.name](self._params[ct.name], batch)
                 jax.block_until_ready(out)
             b *= 2
         self.setup_s += time.monotonic() - t0
+
+    def _input(self, preset: str, bucket: int) -> tuple[dict, bool]:
+        """The paper model's input batch of ``bucket`` rows in ``preset``'s
+        shapes, and whether this call drew it (it is held from then on:
+        cell types of one preset share it)."""
+        batch = self._batches.get((preset, bucket))
+        if batch is not None:
+            return batch, False
+        batch = make_random_batch(self.model_name, preset, bucket)
+        self._batches[preset, bucket] = batch
+        return batch, True
 
     # ------------------------------------------------------------- pool ops
     def configure(self, config) -> None:
@@ -259,7 +279,7 @@ class ClusterEngine:
         ok = 0
         for i, (arrival, bsz) in enumerate(zip(workload.arrivals * time_scale,
                                                workload.batches)):
-            with tracing.span("live.serve", batch=int(bsz)):
+            with tracing.span("live.serve", batch=int(bsz)) as sp:
                 idle = [c for c in live if c.busy_until <= arrival]
                 cell = idle[0] if idle else min(live,
                                                 key=lambda c: c.busy_until)
@@ -272,8 +292,9 @@ class ClusterEngine:
                     # number of compiled executables per cell (standard
                     # serving practice)
                     bucket = 1 << int(np.ceil(np.log2(max(int(bsz), 1))))
-                    batch = make_random_batch(self.model_name,
-                                              cell.cell_type.preset, bucket)
+                    batch, drawn = self._input(cell.cell_type.preset, bucket)
+                    if sp is not None:
+                        sp.args["drawn"] = int(drawn)
                 svc = self._execute(cell, batch, llm)
                 if i in keep:
                     self.kept[i] = (cell.last_output if llm
